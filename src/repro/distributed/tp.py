@@ -31,50 +31,42 @@ back into the models or serving packages.
 from __future__ import annotations
 
 import contextlib
+import threading
 from typing import Optional
 
 import jax
 
 # Name of the mesh axis the current trace is sharded over (None =
-# unsharded trace — every hook no-ops).
-_AXIS: Optional[str] = None
+# unsharded trace — every hook no-ops).  Per thread: the fleet loop
+# traces each replica's phases on that replica's own thread, and one
+# replica leaving its context must not end another's.
+_STATE = threading.local()
 
 
 def axis() -> Optional[str]:
     """The active tensor-parallel mesh axis name, or None."""
-    return _AXIS
+    return getattr(_STATE, "axis", None)
 
 
 def axis_size() -> int:
     """Size of the active tp axis (1 when no context is active)."""
-    if _AXIS is None:
+    ax = axis()
+    if ax is None:
         return 1
-    return jax.lax.psum(1, _AXIS)
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (jax >= 0.6, check_vma) or the experimental API
-    (jax 0.4.x, check_rep) — replication checking off in both, since the
-    serving bodies mix sharded and replicated leaves freely."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.lax.psum(1, ax)
 
 
 @contextlib.contextmanager
 def tensor_parallel(axis_name: str = "model"):
-    """Mark the enclosed trace as running inside a shard_map over
-    ``axis_name``; model hooks become collective-aware for its scope."""
-    global _AXIS
-    prev = _AXIS
-    _AXIS = axis_name
+    """Mark the enclosed trace (on this thread) as running inside a
+    shard_map over ``axis_name``; model hooks become collective-aware
+    for its scope."""
+    prev = axis()
+    _STATE.axis = axis_name
     try:
         yield
     finally:
-        _AXIS = prev
+        _STATE.axis = prev
 
 
 def tp_plan(cfg, tp: int) -> dict:

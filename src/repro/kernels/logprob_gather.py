@@ -24,6 +24,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+LANES = 128
 
 
 def _kernel(h_ref, w_ref, lab_ref, o_ref, m_ref, s_ref, p_ref, *,
@@ -36,28 +37,28 @@ def _kernel(h_ref, w_ref, lab_ref, o_ref, m_ref, s_ref, p_ref, *,
         s_ref[...] = jnp.zeros_like(s_ref)
         p_ref[...] = jnp.full_like(p_ref, NEG)
 
-    h = h_ref[...].astype(jnp.float32)          # (Tt, d)
-    w = w_ref[...].astype(jnp.float32)          # (d, Vt)
-    logits = jnp.dot(h, w, preferred_element_type=jnp.float32)  # (Tt, Vt)
+    # operands stay in their storage dtype; the MXU accumulates in f32
+    logits = jnp.dot(h_ref[...], w_ref[...],
+                     preferred_element_type=jnp.float32)  # (Tt, Vt)
 
     v0 = j * vt
     vidx = v0 + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     valid = vidx < vocab_size
     logits = jnp.where(valid, logits, NEG)
 
-    # online logsumexp
-    m_old = m_ref[...]
-    m_new = jnp.maximum(m_old, jnp.max(logits, axis=-1))
+    # online logsumexp (scratch rows broadcast over 128 lanes)
+    m_old = m_ref[:, :1]                          # (Tt, 1)
+    m_new = jnp.maximum(m_old, jnp.max(logits, axis=-1, keepdims=True))
     scale = jnp.exp(m_old - m_new)
-    s_ref[...] = s_ref[...] * scale + jnp.sum(
-        jnp.exp(logits - m_new[:, None]), axis=-1)
-    m_ref[...] = m_new
+    s_new = s_ref[:, :1] * scale + jnp.sum(jnp.exp(logits - m_new),
+                                           axis=-1, keepdims=True)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    s_ref[...] = jnp.broadcast_to(s_new, s_ref.shape)
 
     # gather the label logit if it falls in this vocab tile
-    lab = lab_ref[...]                           # (Tt,)
-    hit = vidx == lab[:, None]
-    p_ref[...] = jnp.maximum(p_ref[...],
-                             jnp.max(jnp.where(hit, logits, NEG), axis=-1))
+    hit = vidx == lab_ref[...]                    # (Tt, Vt) vs (Tt, 1)
+    picked = jnp.max(jnp.where(hit, logits, NEG), axis=-1, keepdims=True)
+    p_ref[...] = jnp.maximum(p_ref[...], picked)
 
     @pl.when(j == num_vt - 1)
     def _finish():
@@ -67,20 +68,25 @@ def _kernel(h_ref, w_ref, lab_ref, o_ref, m_ref, s_ref, p_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("vocab_size", "tt", "vt", "interpret"))
 def logprob_gather_pallas(h, w, labels, vocab_size: int, *, tt: int = 256,
-                          vt: int = 2048, interpret: bool = False):
-    """h: (B,S,d); w: (d,V); labels: (B,S) -> (B,S) fp32."""
+                          vt: int = 512, interpret: bool = False):
+    """h: (B,S,d); w: (d,V); labels: (B,S) -> (B,S) fp32.
+
+    Default tiles keep scoped VMEM well under 16 MiB at d = 2048 in
+    bf16: double-buffered h (2 x 1 MiB) and w (2 x 2 MiB) blocks plus
+    the (Tt, Vt) f32 logits and their exp.
+    """
     B, S, d = h.shape
     V = w.shape[1]
     T = B * S
     hf = h.reshape(T, d)
-    lab = labels.reshape(T)
-    tt = min(tt, T)
+    lab = labels.reshape(T, 1).astype(jnp.int32)
+    tt = min(tt, -(-T // 8) * 8)
     vt = min(vt, V)
     # pad T to a multiple of tt
     Tp = (T + tt - 1) // tt * tt
     if Tp != T:
         hf = jnp.pad(hf, ((0, Tp - T), (0, 0)))
-        lab = jnp.pad(lab, (0, Tp - T))
+        lab = jnp.pad(lab, ((0, Tp - T), (0, 0)))
     num_vt = (V + vt - 1) // vt
 
     out = pl.pallas_call(
@@ -90,15 +96,15 @@ def logprob_gather_pallas(h, w, labels, vocab_size: int, *, tt: int = 256,
         in_specs=[
             pl.BlockSpec((tt, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, vt), lambda i, j: (0, j)),
-            pl.BlockSpec((tt,), lambda i, j: (i,)),
+            pl.BlockSpec((tt, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tt,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Tp,), jnp.float32),
+        out_specs=pl.BlockSpec((tt, LANES), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Tp, LANES), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((tt,), jnp.float32),
-            pltpu.VMEM((tt,), jnp.float32),
-            pltpu.VMEM((tt,), jnp.float32),
+            pltpu.VMEM((tt, LANES), jnp.float32),   # running max
+            pltpu.VMEM((tt, LANES), jnp.float32),   # running sum-exp
+            pltpu.VMEM((tt, LANES), jnp.float32),   # picked label logit
         ],
         interpret=interpret,
     )(hf, w, lab)
-    return out[:T].reshape(B, S)
+    return out[:T, 0].reshape(B, S)

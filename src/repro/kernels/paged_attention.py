@@ -8,9 +8,11 @@ builds.  Instead the block table is a *scalar-prefetch* operand
 ``pt[b, i]`` to DMA exactly the physical page for logical block ``i`` of
 request ``b`` — the gather happens in the grid indexing, not in compute.
 
-Grid: ``(B, H, nblk)`` with the block sweep innermost; online-softmax
-accumulators (m, l, acc) live in VMEM scratch across the sweep, as in
-``flash_attention.py``.  GQA reads kv head ``h // G``.  Validity is the
+Grid: ``(B, nblk)`` with the block sweep innermost.  One cell DMAs one
+whole physical page (all kv heads) and folds it into online-softmax
+accumulators (m, l, acc) that live in VMEM scratch across the sweep, as
+in ``flash_attention.py``.  GQA: the G query heads of kv head g are one
+``(G, hd)`` matmul operand (padded to 8 sublanes).  Validity is the
 absolute-layout decode mask: position ``kpos = i * ps + lane`` is live iff
 ``kpos <= pos[b]`` (and ``kpos > pos[b] - window`` for sliding-window
 layers) — stale rows of partially-filled or recycled pages are masked, so
@@ -26,12 +28,58 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+LANES = 128
 
 
-def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-            acc_ref, *, scale: float, window: int, ps: int, nblk: int):
+def _grouped_query(q, KV: int):
+    """(B,1,H,hd) -> (B,KV,Gp,hd) float32, the G = H/KV query heads of a
+    kv head padded with zero rows to a multiple of 8 sublanes."""
+    B, _, H, hd = q.shape
+    G = H // KV
+    Gp = -(-G // 8) * 8
+    qg = q[:, 0].astype(jnp.float32).reshape(B, KV, G, hd)
+    return jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+
+
+def _online_update(q, k, v, kpos, pos, m_ref, l_ref, acc_ref, g, *,
+                   scale: float, window: int):
+    """Fold one page of one kv head into the (m, l, acc) accumulators.
+
+    q: (Gp, hd) f32; k/v: (ps, hd) f32; kpos: (1, ps) absolute
+    positions of the page's rows.  m/l scratch rows are broadcast over
+    their 128 lanes; lane 0 is read back.
+    """
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    mask = kpos <= pos                               # (1, ps)
+    if window:
+        mask &= kpos > pos - window
+    s = jnp.where(mask, s, NEG)                      # (Gp, ps)
+    m_old = m_ref[g][:, :1]                          # (Gp, 1)
+    m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_old - m_new)
+    l_new = l_ref[g][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[g] = acc_ref[g] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+    l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+
+def _kernel(pt_ref, pos_ref, *refs, scale: float, window: int, ps: int,
+            nblk: int, kv: int, hd: int, quantized: bool):
+    """One (request, logical block) grid cell: the whole physical page,
+    every kv head.  ``quantized`` adds the per-page per-kv-head scale
+    operands (scalar prefetch) and dequantizes K/V in registers — the
+    fp copy of a quantized page is never written anywhere."""
+    if quantized:
+        ks_ref, vs_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, \
+            acc_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
-    i = pl.program_id(2)
+    i = pl.program_id(1)
+    pos = pos_ref[b]
 
     @pl.when(i == 0)
     def _init():
@@ -39,81 +87,87 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0, :].astype(jnp.float32)          # (hd,)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)       # (ps, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-
-    s = jnp.dot(k, q[:, None], preferred_element_type=jnp.float32)[:, 0]
-    s = s * scale                                    # (ps,)
-    kpos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)[:, 0]
-    pos = pos_ref[b]
-    mask = kpos <= pos
-    if window:
-        mask &= kpos > pos - window
-    s = jnp.where(mask, s, NEG)
-
-    m_old = m_ref[0]
-    m_new = jnp.maximum(m_old, jnp.max(s))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_old - m_new)
-    l_ref[0] = l_ref[0] * alpha + jnp.sum(p)
-    acc_ref[0, :] = acc_ref[0, :] * alpha + jnp.dot(
-        p[None, :], v, preferred_element_type=jnp.float32)[0]
-    m_ref[0] = m_new
+    # pages wholly past pos contribute exp(NEG - m) == 0 exactly; skip
+    # their compute (their DMA is skipped by the clamped index map)
+    @pl.when(i * ps <= pos)
+    def _page():
+        page = pt_ref[b, i]
+        kpos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        for g in range(kv):
+            k = k_ref[0, :, g * hd:(g + 1) * hd].astype(jnp.float32)
+            v = v_ref[0, :, g * hd:(g + 1) * hd].astype(jnp.float32)
+            if quantized:
+                k = k * ks_ref[page, g]
+                v = v * vs_ref[page, g]
+            _online_update(q_ref[0, g], k, v, kpos, pos, m_ref, l_ref,
+                           acc_ref, g, scale=scale, window=window)
 
     @pl.when(i == nblk - 1)
     def _finish():
-        l = jnp.maximum(l_ref[0], 1e-30)
-        o_ref[0, 0, :] = (acc_ref[0, :] / l).astype(o_ref.dtype)
+        for g in range(kv):
+            l = jnp.maximum(l_ref[g][:, :1], 1e-30)
+            o_ref[0, g] = acc_ref[g] / l
 
 
-def _quant_kernel(pt_ref, pos_ref, ks_ref, vs_ref, q_ref, k_ref, v_ref,
-                  o_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                  window: int, ps: int, nblk: int, g: int):
-    """Fused-dequant variant of ``_kernel``: K/V blocks arrive as int8
-    (or fp8) codes and are scaled back to float32 in registers — the fp
-    copy of the page is never written anywhere.  The per-page scales ride
-    the same scalar-prefetch path as the block table, so the scale lookup
-    ``ks[pt[b, i], h // G]`` is SMEM reads, not an HBM gather."""
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    i = pl.program_id(2)
+def _paged_call(q, kp, vp, scales, pt, pos, *, window, scale, interpret):
+    """Shared pallas_call for the fp and quantized kernels.
 
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    Grid ``(B, nblk)`` with the block sweep innermost.  K/V pools are
+    viewed as ``(P, ps, KV*hd)`` (a free reshape) and DMA'd one whole
+    physical page per cell, so every block's trailing dims equal the
+    array's and any page size / head count tiles.  The index map clamps
+    the logical block to the request's last live one, so pages past
+    ``pos`` are never fetched.
+    """
+    B, _, H, hd = q.shape
+    P, ps, KV, _ = kp.shape
+    G = H // KV
+    nblk = pt.shape[1]
+    if scale is None:
+        scale = hd ** -0.5
+    qg = _grouped_query(q, KV)
+    Gp = qg.shape[2]
+    kp2 = kp.reshape(P, ps, KV * hd)
+    vp2 = vp.reshape(P, ps, KV * hd)
+    quantized = scales is not None
+    n_pref = 4 if quantized else 2
 
-    page = pt_ref[b, i]
-    ksc = ks_ref[page, h // g]
-    vsc = vs_ref[page, h // g]
-    q = q_ref[0, 0, :].astype(jnp.float32)          # (hd,)
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ksc  # (ps, hd) dequant
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vsc
+    def live_block(b, i, pt_, pos_):
+        return pt_[b, jnp.minimum(i, jnp.maximum(pos_[b], 0) // ps)]
 
-    s = jnp.dot(k, q[:, None], preferred_element_type=jnp.float32)[:, 0]
-    s = s * scale                                    # (ps,)
-    kpos = i * ps + jax.lax.broadcasted_iota(jnp.int32, (ps, 1), 0)[:, 0]
-    pos = pos_ref[b]
-    mask = kpos <= pos
-    if window:
-        mask &= kpos > pos - window
-    s = jnp.where(mask, s, NEG)
+    def qmap(b, i, *pref):
+        return (b, 0, 0, 0)
 
-    m_old = m_ref[0]
-    m_new = jnp.maximum(m_old, jnp.max(s))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_old - m_new)
-    l_ref[0] = l_ref[0] * alpha + jnp.sum(p)
-    acc_ref[0, :] = acc_ref[0, :] * alpha + jnp.dot(
-        p[None, :], v, preferred_element_type=jnp.float32)[0]
-    m_ref[0] = m_new
+    def kvmap(b, i, pt_, pos_, *rest):
+        return (live_block(b, i, pt_, pos_), 0, 0)
 
-    @pl.when(i == nblk - 1)
-    def _finish():
-        l = jnp.maximum(l_ref[0], 1e-30)
-        o_ref[0, 0, :] = (acc_ref[0, :] / l).astype(o_ref.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_pref,
+        grid=(B, nblk),
+        in_specs=[
+            pl.BlockSpec((1, KV, Gp, hd), qmap),
+            pl.BlockSpec((1, ps, KV * hd), kvmap),
+            pl.BlockSpec((1, ps, KV * hd), kvmap),
+        ],
+        out_specs=pl.BlockSpec((1, KV, Gp, hd), qmap),
+        scratch_shapes=[
+            pltpu.VMEM((KV, Gp, LANES), jnp.float32),     # m
+            pltpu.VMEM((KV, Gp, LANES), jnp.float32),     # l
+            pltpu.VMEM((KV, Gp, hd), jnp.float32),        # acc
+        ],
+    )
+    prefetch = (pt.astype(jnp.int32), pos.astype(jnp.int32))
+    if quantized:
+        prefetch += tuple(s.astype(jnp.float32) for s in scales)
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=scale, window=window, ps=ps,
+                          nblk=nblk, kv=KV, hd=hd, quantized=quantized),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, Gp, hd), jnp.float32),
+        interpret=interpret,
+    )(*prefetch, qg, kp2, vp2)
+    out = out[:, :, :G].reshape(B, 1, H, hd)
+    return out.astype(q.dtype)
 
 
 @functools.partial(jax.jit,
@@ -124,47 +178,11 @@ def paged_attention_quant_pallas(q, kp, vp, ks, vs, pt, pos, *, window=0,
     scales; pt: (B,nblk); pos: (B,).
 
     Same grid/BlockSpec structure as ``paged_attention_pallas`` with two
-    extra scalar-prefetch operands (the scale tensors) consumed by the
-    fused dequantization in ``_quant_kernel``.
+    extra scalar-prefetch operands (the scale tensors): the lookup
+    ``ks[pt[b, i], g]`` is an SMEM read, not an HBM gather.
     """
-    B, _, H, hd = q.shape
-    _, ps, KV, _ = kp.shape
-    G = H // KV
-    nblk = pt.shape[1]
-    if scale is None:
-        scale = hd ** -0.5
-    q3 = q[:, 0]                                     # (B, H, hd)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,                       # pt, pos, ks, vs
-        grid=(B, H, nblk),
-        in_specs=[
-            pl.BlockSpec((1, 1, hd),
-                         lambda b, h, i, pt, pos, ks, vs: (b, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, i, pt, pos, ks, vs, g=G:
-                         (pt[b, i], 0, h // g, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, i, pt, pos, ks, vs, g=G:
-                         (pt[b, i], 0, h // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda b, h, i, pt, pos, ks, vs: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((8,), jnp.float32),           # m (row 0 used)
-            pltpu.VMEM((8,), jnp.float32),           # l
-            pltpu.VMEM((8, hd), jnp.float32),        # acc
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_quant_kernel, scale=scale, window=window,
-                          ps=ps, nblk=nblk, g=G),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        interpret=interpret,
-    )(pt.astype(jnp.int32), pos.astype(jnp.int32),
-      ks.astype(jnp.float32), vs.astype(jnp.float32), q3, kp, vp)
-    return out[:, None]
+    return _paged_call(q, kp, vp, (ks, vs), pt, pos, window=window,
+                       scale=scale, interpret=interpret)
 
 
 @functools.partial(jax.jit,
@@ -172,38 +190,5 @@ def paged_attention_quant_pallas(q, kp, vp, ks, vs, pt, pos, *, window=0,
 def paged_attention_pallas(q, kp, vp, pt, pos, *, window=0, scale=None,
                            interpret: bool = False):
     """q: (B,1,H,hd); kp/vp: (P,ps,KV,hd); pt: (B,nblk); pos: (B,)."""
-    B, _, H, hd = q.shape
-    _, ps, KV, _ = kp.shape
-    G = H // KV
-    nblk = pt.shape[1]
-    if scale is None:
-        scale = hd ** -0.5
-    q3 = q[:, 0]                                     # (B, H, hd)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                       # pt, pos
-        grid=(B, H, nblk),
-        in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, i, pt, pos: (b, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, i, pt, pos, g=G: (pt[b, i], 0,
-                                                        h // g, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, i, pt, pos, g=G: (pt[b, i], 0,
-                                                        h // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b, h, i, pt, pos: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((8,), jnp.float32),           # m (row 0 used)
-            pltpu.VMEM((8,), jnp.float32),           # l
-            pltpu.VMEM((8, hd), jnp.float32),        # acc
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, window=window, ps=ps,
-                          nblk=nblk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
-        interpret=interpret,
-    )(pt.astype(jnp.int32), pos.astype(jnp.int32), q3, kp, vp)
-    return out[:, None]
+    return _paged_call(q, kp, vp, None, pt, pos, window=window,
+                       scale=scale, interpret=interpret)

@@ -97,7 +97,7 @@ def paged_attention_quant_ref(q, kp, vp, ks, vs, pt, pos, *, window=0,
     ``fp ~= code * scale``; pt: (B,nblk) block table; pos: (B,) ->
     (B,1,H,hd).
 
-    The *production* CPU path (``REPRO_USE_PALLAS=0``): same gather /
+    The CPU reference path of ``kernels.ops``: same gather /
     mask / softmax structure as ``paged_attention_ref`` with the
     dequantization folded into the gather (codes -> f32 times the
     per-row page scale).  It matches the fused Pallas kernel to f32
@@ -141,56 +141,61 @@ def paged_attention_quant_cell_ref(q, kp, vp, ks, vs, pt, pos, *, window=0,
     """Bit-exact oracle for the fused-dequant Pallas kernel.
 
     Same signature as :func:`paged_attention_quant_ref`, but mirrors
-    ``_quant_kernel`` *exactly*, cell by cell: one (request, head)
-    online-softmax sweep over logical blocks per grid cell, same op
-    structure and f32 intermediate order.  The per-cell structure is
-    load-bearing for the bit-identity test in tests/test_quant.py: XLA's
-    CPU backend picks reduction strategies by operand *shape*, so any
-    batched (vmapped / einsum) formulation of the same math accumulates
-    in a different order than the kernel's per-cell dots and drifts by a
-    few ulps.  The unrolled graph compiles slowly (seconds to tens of
-    seconds) — test oracle only, never dispatched by ``kernels.ops``.
+    ``paged_attention._kernel`` *exactly*, cell by cell: per request an
+    online-softmax sweep over logical blocks, and per block one
+    ``(Gp, hd) x (ps, hd)^T`` score matmul per kv head over its G query
+    heads (zero-padded to Gp = 8k rows), same op structure and f32
+    intermediate order.  The per-cell structure is load-bearing for the
+    bit-identity test in tests/test_quant.py: XLA's CPU backend picks
+    reduction strategies by operand *shape*, so any batched (vmapped /
+    einsum) formulation of the same math accumulates in a different
+    order than the kernel's per-cell dots and drifts by a few ulps.  The
+    unrolled graph compiles slowly (seconds to tens of seconds) — test
+    oracle only, never dispatched by ``kernels.ops``.
     """
     B, _, H, hd = q.shape
     P, ps, KV, _ = kp.shape
     nblk = pt.shape[1]
     G = H // KV
+    Gp = -(-G // 8) * 8
     if scale is None:
         scale = hd ** -0.5
     ptc = pt.astype(jnp.int32)
     posc = pos.astype(jnp.int32)
-    lanes = jnp.arange(ps, dtype=jnp.int32)
+    lanes = jnp.arange(ps, dtype=jnp.int32)[None, :]      # (1, ps)
+    qg = jnp.pad(q[:, 0].astype(jnp.float32).reshape(B, KV, G, hd),
+                 ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
 
-    def cell(b, h):
-        qv = q[b, 0, h, :].astype(jnp.float32)            # (hd,)
-        m = jnp.float32(-1e30)
-        l = jnp.float32(0.0)
-        acc = jnp.zeros((hd,), jnp.float32)
+    def request(b):
+        m = [jnp.full((Gp, 1), -1e30, jnp.float32)] * KV
+        l = [jnp.zeros((Gp, 1), jnp.float32)] * KV
+        acc = [jnp.zeros((Gp, hd), jnp.float32)] * KV
         for i in range(nblk):
             page = ptc[b, i]
-            k = kp[page, :, h // G, :].astype(jnp.float32) \
-                * ks[page, h // G]                        # (ps, hd)
-            v = vp[page, :, h // G, :].astype(jnp.float32) \
-                * vs[page, h // G]
-            s = jnp.dot(k, qv[:, None],
-                        preferred_element_type=jnp.float32)[:, 0] * scale
             kpos = i * ps + lanes
             mask = kpos <= posc[b]
             if window:
                 mask &= kpos > posc[b] - window
-            s = jnp.where(mask, s, -1e30)
-            m_new = jnp.maximum(m, jnp.max(s))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + jnp.sum(p)
-            acc = acc * alpha + jnp.dot(
-                p[None, :], v, preferred_element_type=jnp.float32)[0]
-            m = m_new
-        return acc / jnp.maximum(l, 1e-30)
+            for g in range(KV):
+                k = kp[page, :, g, :].astype(jnp.float32) * ks[page, g]
+                v = vp[page, :, g, :].astype(jnp.float32) * vs[page, g]
+                s = jax.lax.dot_general(
+                    qg[b, g], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(mask, s, -1e30)
+                m_new = jnp.maximum(m[g], jnp.max(s, axis=-1,
+                                                  keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m[g] - m_new)
+                l[g] = l[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                acc[g] = acc[g] * alpha + jnp.dot(
+                    p, v, preferred_element_type=jnp.float32)
+                m[g] = m_new
+        return jnp.stack([acc[g] / jnp.maximum(l[g], 1e-30)
+                          for g in range(KV)])            # (KV, Gp, hd)
 
-    out = jnp.stack([jnp.stack([cell(b, h) for h in range(H)])
-                     for b in range(B)])                  # (B, H, hd)
-    return out[:, None].astype(q.dtype)
+    out = jnp.stack([request(b) for b in range(B)])[:, :, :G]
+    return out.reshape(B, 1, H, hd).astype(q.dtype)
 
 
 def rwkv6_scan_ref(r, k, v, w, u, state):

@@ -31,18 +31,19 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref,
     def _init():
         s_ref[...] = s0_ref[0]
 
-    u = u_ref[0]                                 # (hd,)
+    u = u_ref[0].astype(jnp.float32).T           # (hd, 1)
 
     def step(t, _):
-        rt = r_ref[0, t].astype(jnp.float32)     # (hd,)
-        kt = k_ref[0, t].astype(jnp.float32)
-        vt = v_ref[0, t].astype(jnp.float32)
-        wt = w_ref[0, t].astype(jnp.float32)
+        row = pl.ds(t, 1)
+        rt = r_ref[0, row, :].T                  # (hd, 1)
+        kt = k_ref[0, row, :].T                  # (hd, 1)
+        vt = v_ref[0, row, :]                    # (1, hd)
+        wt = w_ref[0, row, :].T                  # (hd, 1)
         S = s_ref[...]                           # (hd, hd)
-        kv = kt[:, None] * vt[None, :]
-        out = jnp.sum((S + u[:, None] * kv) * rt[:, None], axis=0)
-        o_ref[0, t] = out
-        s_ref[...] = wt[:, None] * S + kv
+        kv = kt * vt
+        out = jnp.sum((S + u * kv) * rt, axis=0, keepdims=True)
+        o_ref[0, row, :] = out
+        s_ref[...] = wt * S + kv
         return 0
 
     jax.lax.fori_loop(0, chunk, step, 0)
@@ -63,20 +64,19 @@ def rwkv6_scan_pallas(r, k, v, w, u, state, *, chunk: int = 64,
     chunk = min(chunk, T)
     Tp = (T + chunk - 1) // chunk * chunk
 
-    def prep(a):
-        a = jnp.moveaxis(a, 2, 1).reshape(B * H, T, hd)  # (BH, T, hd)
+    def prep(a, pad_val=0.0):
+        # f32 in HBM: the kernel loads one row per step at a dynamic
+        # sublane offset, which packed (bf16) tiles do not allow
+        a = jnp.moveaxis(a, 2, 1).reshape(B * H, T, hd).astype(jnp.float32)
         if Tp != T:
-            # pad with decay=1, k=0 -> state unchanged on padded steps
-            pad_val = 1.0 if a is None else 0.0
             a = jnp.pad(a, ((0, 0), (0, Tp - T), (0, 0)),
                         constant_values=pad_val)
         return a
 
     rr, kk, vv = prep(r), prep(k), prep(v)
-    ww = jnp.moveaxis(w, 2, 1).reshape(B * H, T, hd)
-    if Tp != T:
-        ww = jnp.pad(ww, ((0, 0), (0, Tp - T), (0, 0)), constant_values=1.0)
-    uu = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, hd)
+    # pad with decay=1, k=0 -> state unchanged on padded steps
+    ww = prep(w, 1.0)
+    uu = jnp.broadcast_to(u[None], (B, H, hd)).reshape(B * H, 1, hd)
     s0 = state.reshape(B * H, hd, hd).astype(jnp.float32)
     num_chunks = Tp // chunk
 
@@ -88,7 +88,7 @@ def rwkv6_scan_pallas(r, k, v, w, u, state, *, chunk: int = 64,
             pl.BlockSpec((1, chunk, hd), lambda g, c: (g, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda g, c: (g, c, 0)),
             pl.BlockSpec((1, chunk, hd), lambda g, c: (g, c, 0)),
-            pl.BlockSpec((1, hd), lambda g, c: (g, 0)),
+            pl.BlockSpec((1, 1, hd), lambda g, c: (g, 0, 0)),
             pl.BlockSpec((1, hd, hd), lambda g, c: (g, 0, 0)),
         ],
         out_specs=[

@@ -19,15 +19,28 @@ from typing import List
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.config import MULTI_POD, SINGLE_POD, MeshConfig
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    jax's default is *Explicit* axes, under which a sharding rule that
+    names one axis twice (e.g. FSDP ``data`` on a weight plus ``data``
+    on the batch) raises instead of letting the compiler insert the
+    collectives; every mesh of this repo is built here instead.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The 256-chip single-pod (or 512-chip two-pod) production mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
@@ -37,7 +50,7 @@ def mesh_config(*, multi_pod: bool = False) -> MeshConfig:
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over however many host devices exist (tests)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def carve_submeshes(num_replicas: int, shape=(1, 2),

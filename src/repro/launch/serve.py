@@ -1,12 +1,19 @@
-"""GSI serving launcher: train a draft/target/PRM triple on the synthetic
-reasoning task (or load checkpoints), then serve queued requests through
-the continuous-batching scheduler and report accuracy / acceptance /
-throughput / latency-model numbers.
+"""GSI serving launcher: build a draft/target/PRM triple, then serve
+queued requests through the continuous-batching scheduler and report
+accuracy / acceptance / throughput / latency numbers.
 
     PYTHONPATH=src python -m repro.launch.serve --requests 16 --n 4 \
         --method gsi --capacity 8 [--train-steps 300] \
         [--paged --replicas 2 --router affinity] [--sync | --async] \
         [--mesh-shape 1x2 | --tp 2]
+
+By default the triple is the toy one (vocab 16), trained on the
+synthetic reasoning task first — the CPU configuration of the tests.
+``--draft/--target/--prm NAME`` serve registered configs at their
+published widths instead (e.g. ``qwen2.5-math-1.5b`` / ``qwen3-1.7b`` /
+``qwen2.5-math-1.5b``; the PRM gets a reward head), with seeded random
+weights from ``--seed`` and no training; ``--max-seq`` sizes the KV
+cache.  Start-up prints the platform, device kind and device count.
 
 ``--replicas N`` serves through N data-parallel replicas (one engine,
 page pool and radix index each) behind the preamble-affinity router.
@@ -31,26 +38,25 @@ import time
 import jax
 import numpy as np
 
-from repro.config import GSIConfig, ModelConfig, TrainConfig
-from repro.data import SyntheticReasoningTask, PAD
+from repro.config import GSIConfig, ModelConfig, TrainConfig, get_config
+from repro.data import SyntheticReasoningTask
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import carve_submeshes
+from repro.models import build_model
 from repro.serving import GSIScheduler, GSIServingEngine, ReplicaRouter
 from repro.serving.router import HASH_TIERS, POLICIES
-from repro.serving.latency import HW_V5E, LatencyModel, ModelCost
 from repro.train import Trainer
 
 
-#: XLA / allocator environment tuning (the olmax ``run.sh`` recipe):
-#: a single host platform device (no fake TPU-CPU fan-out), step markers
-#: at the outer while loop so profiles attribute whole decode steps, a
-#: bounded preallocation fraction instead of the 75%-and-grow default,
-#: and quiet allocator large-alloc warnings.  ``setdefault`` semantics —
-#: anything the operator already exported wins.
+#: XLA / allocator environment tuning: step markers at the outer while
+#: loop so profiles attribute whole decode steps, no up-front
+#: preallocation, and quiet allocator large-alloc warnings.
+#: ``setdefault`` semantics — anything the operator already exported
+#: wins.  Flags for the TPU runtime belong in ``LIBTPU_INIT_ARGS``, which
+#: a command appends to and never overwrites.
 TUNED_ENV = {
-    "XLA_FLAGS": "--xla_force_host_platform_device_count=1 "
-                 "--xla_step_marker_location="
+    "XLA_FLAGS": "--xla_step_marker_location="
                  "STEP_MARK_AT_TOP_LEVEL_WHILE_LOOP",
-    "XLA_PYTHON_CLIENT_MEM_FRACTION": "0.8",
     "XLA_PYTHON_CLIENT_PREALLOCATE": "false",
     "TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD": "60000000000",
     "TF_CPP_MIN_LOG_LEVEL": "4",
@@ -71,6 +77,13 @@ def apply_tuned_env(env=None) -> dict:
         if target.setdefault(key, val) == val:
             applied[key] = val
     return applied
+
+
+def device_report() -> dict:
+    """The devices this process runs on, as JAX reports them."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 def parse_mesh_shape(text: str):
@@ -99,6 +112,67 @@ def toy_triple(vocab: int = 16):
                                  d_model=160, head_dim=40, d_ff=448)
     prm = dataclasses.replace(target, name="sx-prm", reward_head=True)
     return draft, target, prm
+
+
+def resolve_triple(draft: str, target: str, prm: str):
+    """Registered configs by name; the PRM config gets a reward head
+    (the same architecture plus the scalar head, as in :func:`toy_triple`).
+    All three must share one vocabulary."""
+    cfgs = (get_config(draft), get_config(target),
+            dataclasses.replace(get_config(prm), reward_head=True))
+    vocabs = {c.vocab_size for c in cfgs}
+    if len(vocabs) != 1:
+        raise ValueError(f"draft/target/PRM must share a vocabulary, got "
+                         f"{[c.vocab_size for c in cfgs]}")
+    return cfgs
+
+
+def init_triple(cfgs, seed: int):
+    """Seeded random weights for each config (model i from
+    ``fold_in(key(seed), i)``), materialized directly on the first
+    device in each config's ``param_dtype``.
+
+    The key is an RBG key: XLA's bit generator compiles the init of a
+    published-width model in seconds, where threefry takes most of a
+    minute per model."""
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    key = jax.random.key(seed, impl="rbg")
+    return tuple(
+        jax.jit(build_model(cfg).init, out_shardings=sharding)(
+            jax.random.fold_in(key, i))
+        for i, cfg in enumerate(cfgs))
+
+
+def param_bytes(params) -> int:
+    """Bytes held by one parameter tree."""
+    return sum(int(a.size) * a.dtype.itemsize
+               for a in jax.tree.leaves(params))
+
+
+def build_engines(cfgs, params, gcfg, *, replicas: int = 1,
+                  mesh_shape=None, **engine_kw):
+    """One :class:`GSIServingEngine` per replica over ``params``.
+
+    With ``mesh_shape`` the visible devices are carved into one
+    ``(data, model)`` submesh per replica and each replica's target runs
+    tensor-parallel over it.  Without one, replica r lives on device
+    ``r mod count``, so replicas spread over the chips instead of
+    stacking on the first.  ``engine_kw`` goes to every engine.
+    """
+    draft_cfg, target_cfg, prm_cfg = cfgs
+    devices = jax.devices()
+    if mesh_shape is not None:
+        submeshes = carve_submeshes(replicas, mesh_shape)
+        print(f"mesh: {replicas} replica(s) x "
+              f"{mesh_shape[0]}x{mesh_shape[1]} (data x model) submesh "
+              f"over {len(devices)} visible device(s)", flush=True)
+        place = [dict(mesh=m) for m in submeshes]
+    else:
+        place = [dict(device=devices[r % len(devices)])
+                 for r in range(replicas)]
+    return [GSIServingEngine(draft_cfg, target_cfg, prm_cfg, *params, gcfg,
+                             **engine_kw, **place[r])
+            for r in range(replicas)]
 
 
 def train_triple(task, draft_cfg, target_cfg, prm_cfg, *, steps_draft=200,
@@ -227,7 +301,8 @@ def evaluate_queued(engine, task, problems, rng, *, capacity: int,
     token-stream callback to the first request.  ``cache_dir`` enables
     warm restarts: per-replica radix-cache snapshots are loaded from it
     before serving (if present) and saved back after the run.  Returns
-    accuracy plus throughput/latency.
+    accuracy plus throughput/latency, and the request ids in submission
+    order (``ids``; ``responses`` is keyed by id in finish order).
     """
     sched = make_frontend(engine, capacity=capacity, continuous=continuous,
                           collect_stats=True, policy=policy, sync=sync,
@@ -272,7 +347,7 @@ def evaluate_queued(engine, task, problems, rng, *, capacity: int,
             "prefill_commit_max": sched.stats.prefill_commit_max,
             "prefix": sched.prefix_stats(),
             "pipeline": sched.pipeline_stats(),
-            "stats": sched.stats, "responses": results}
+            "stats": sched.stats, "responses": results, "ids": ids}
 
 
 def main() -> None:
@@ -356,9 +431,19 @@ def main() -> None:
                          "(requires --paged with the prefix cache on)")
     ap.add_argument("--tuned-env", action="store_true",
                     help="apply the XLA/allocator env tuning "
-                         "(XLA_FLAGS step markers + single host device, "
-                         "bounded client mem fraction) before serving "
-                         "and print what was applied")
+                         "(XLA_FLAGS step markers, no preallocation) "
+                         "before serving and print what was applied")
+    ap.add_argument("--draft", default="",
+                    help="registered draft config (with --target/--prm: "
+                         "serve published widths from seeded random "
+                         "weights; default: the trained toy triple)")
+    ap.add_argument("--target", default="",
+                    help="registered target config")
+    ap.add_argument("--prm", default="",
+                    help="registered PRM base config (a reward head is "
+                         "added)")
+    ap.add_argument("--max-seq", type=int, default=128,
+                    help="per-request KV capacity in tokens")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     if args.tuned_env:
@@ -368,12 +453,27 @@ def main() -> None:
             print(f"tuned-env [{mark}] {key}={os.environ[key]}",
                   flush=True)
 
+    enable_compile_cache()
+    dev = device_report()
+    print(f"device: platform={dev['platform']} "
+          f"device_kind={dev['kind']} count={dev['count']}", flush=True)
+
     task = SyntheticReasoningTask(seed=args.seed)
-    draft_cfg, target_cfg, prm_cfg = toy_triple()
-    print("training draft/target/PRM triple ...", flush=True)
-    ps, pb, pp = train_triple(task, draft_cfg, target_cfg, prm_cfg,
+    names = (args.draft, args.target, args.prm)
+    if any(names):
+        if not all(names):
+            raise SystemExit("--draft, --target and --prm go together")
+        cfgs = resolve_triple(*names)
+        print(f"initialising {'/'.join(names)} from seed {args.seed} "
+              f"(published widths, random weights) ...", flush=True)
+        params = init_triple(cfgs, args.seed)
+    else:
+        cfgs = toy_triple()
+        print("training draft/target/PRM triple ...", flush=True)
+        params = train_triple(task, *cfgs,
                               steps_draft=args.train_steps // 2,
-                              steps_target=args.train_steps, seed=args.seed)
+                              steps_target=args.train_steps,
+                              seed=args.seed)
 
     g = GSIConfig(n=args.n, beta=args.beta, threshold_u=args.u,
                   max_step_tokens=8, max_steps=8)
@@ -390,22 +490,12 @@ def main() -> None:
         mesh_shape = parse_mesh_shape(args.mesh_shape)
     elif args.tp > 1:
         mesh_shape = (1, args.tp)
-    submeshes = [None] * args.replicas
-    if mesh_shape is not None:
-        submeshes = carve_submeshes(args.replicas, mesh_shape)
-        print(f"mesh: {args.replicas} replica(s) x "
-              f"{mesh_shape[0]}x{mesh_shape[1]} (data x model) submesh "
-              f"over {len(jax.devices())} visible device(s)", flush=True)
-    engines = [
-        GSIServingEngine(draft_cfg, target_cfg, prm_cfg, ps, pb, pp, g,
-                         mode=args.method, max_seq=128,
-                         paged=args.paged, page_size=args.page_size,
-                         num_pages=args.num_pages,
-                         prefix_cache=not args.no_prefix_cache,
-                         kv_dtype=kv_dtype,
-                         quantize_draft=args.quantize_draft,
-                         mesh=submeshes[i])
-        for i in range(args.replicas)]
+    engines = build_engines(
+        cfgs, params, g, replicas=args.replicas, mesh_shape=mesh_shape,
+        mode=args.method, max_seq=args.max_seq, paged=args.paged,
+        page_size=args.page_size, num_pages=args.num_pages,
+        prefix_cache=not args.no_prefix_cache, kv_dtype=kv_dtype,
+        quantize_draft=args.quantize_draft)
     engine = engines[0]
     problems = [task.sample_problem() for _ in range(args.requests)]
 
@@ -476,14 +566,6 @@ def main() -> None:
           f"wall={res['wall_s']:.1f}s tokens/s={res['tokens_per_s']:.1f} "
           f"p50={res['latency_p50']*1e3:.0f}ms "
           f"p95={res['latency_p95']*1e3:.0f}ms")
-
-    lm = LatencyModel(
-        ModelCost(draft_cfg.param_count(), 1024),
-        ModelCost(target_cfg.param_count(), 4096),
-        ModelCost(prm_cfg.param_count(), 4096), HW_V5E)
-    t = lm.step_time(method=args.method, n=args.n, step_len=6, ctx_len=64,
-                     accept_rate=res["accept_rate"])
-    print(f"latency-model seconds/step on {HW_V5E.name}: {t:.2e}")
 
 
 if __name__ == "__main__":

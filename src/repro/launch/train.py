@@ -23,6 +23,8 @@ from repro.data.lm import lm_batches, prefetch
 from repro.distributed import context as dctx
 from repro.distributed.sharding import (as_shardings, batch_pspec,
                                         param_pspecs)
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import AdamW
 from repro.train import make_train_step
@@ -43,6 +45,7 @@ def main() -> None:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -56,8 +59,7 @@ def main() -> None:
     if n_dev > 1:
         import math
         model_ax = math.gcd(n_dev, 16)
-        mesh = jax.make_mesh((n_dev // model_ax, model_ax),
-                             ("data", "model"))
+        mesh = make_mesh((n_dev // model_ax, model_ax), ("data", "model"))
         dctx.set_mesh(mesh)
         p_sh = as_shardings(param_pspecs(model.param_specs(), mesh, "train"),
                             mesh)

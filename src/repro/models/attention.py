@@ -10,8 +10,9 @@ entries, so long_500k decode stores O(window), not O(seq), per local layer.
 Keys are cached rope-applied (absolute positions), the standard TPU idiom.
 
 On TPU the train/prefill path dispatches to the Pallas flash-attention kernel
-(``repro.kernels.ops.flash_attention``); the pure-jnp path here doubles as its
-oracle and as the CPU/dry-run implementation.
+(``repro.kernels.ops.flash_attention``, see its platform dispatch); the
+pure-jnp path here doubles as its oracle and as the CPU/dry-run
+implementation.
 """
 from __future__ import annotations
 
@@ -79,13 +80,9 @@ def causal_mask(sq: int, sk: int, q_offset=0, window: int = 0):
     return m[None]
 
 
-def _use_flash() -> bool:
-    return os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-
-
 def _full_seq_attention(q, k, v, scale, window: int, causal: bool = True):
-    if _use_flash() and causal:
-        from repro.kernels import ops
+    from repro.kernels import ops
+    if ops.use_kernels() and causal:
         return ops.flash_attention(q, k, v, causal=True, window=window,
                                    scale=scale)
     if causal:

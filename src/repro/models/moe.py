@@ -27,17 +27,6 @@ from repro.models.common import ParamSpec, spec
 from repro.distributed import context as dctx
 
 
-def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map (jax >= 0.6, check_vma) or the experimental API
-    (jax 0.4.x, check_rep) — replication checking off in both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def padded_experts(cfg, model_axis: int = 16) -> int:
     m = max(model_axis, 1)
     return (cfg.num_experts + m - 1) // m * m
@@ -196,7 +185,7 @@ def moe_ffn(cfg, p, x):
 
         data_ax = (batch_axes if len(batch_axes) > 1 else
                    (batch_axes[0] if batch_axes else None))
-        y, aux = _shard_map(
+        y, aux = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(None, None), P(None, None),
                       P("model", None, data_ax), P("model", None, data_ax),
@@ -209,7 +198,7 @@ def moe_ffn(cfg, p, x):
                         "shared_down": P(("model",) + batch_axes
                                          if batch_axes else "model",
                                          None)})),
-            out_specs=(P(None, None), P()),
+            out_specs=(P(None, None), P()), check_vma=False,
         )(xf, p["router"], p["we_gate"], p["we_up"], p["we_down"], shared)
         return y.reshape(B, S, d), jnp.mean(aux)
 
@@ -237,7 +226,7 @@ def moe_ffn(cfg, p, x):
     else:
         tok_spec = P(batch_axes if len(batch_axes) > 1 else batch_axes[0],
                      None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(tok_spec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None),
@@ -245,7 +234,7 @@ def moe_ffn(cfg, p, x):
                    {"shared_gate": P(None, "model"),
                     "shared_up": P(None, "model"),
                     "shared_down": P("model", None)})),
-        out_specs=(tok_spec, P()),
+        out_specs=(tok_spec, P()), check_vma=False,
     )(xf, p["router"], p["we_gate"], p["we_up"], p["we_down"], shared)
     return y.reshape(B, S, d), jnp.mean(aux)
 

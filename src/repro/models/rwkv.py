@@ -110,10 +110,6 @@ def _wkv_scan(r, k, v, w, u, state):
     return jnp.moveaxis(outs, 0, 1), state_new  # (B,T,H,hd)
 
 
-def _use_kernel() -> bool:
-    return os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-
-
 def _use_chunked() -> bool:
     """Chunked-parallel WKV (matmul form) — used by the dry-run lowering.
 
@@ -199,8 +195,8 @@ def time_mix(cfg, p, x, state, mode: str):
     w = _decay(p, xw).reshape(B, T, H, hd)
     u = p["bonus_u"].astype(jnp.float32)
 
-    if _use_kernel() and T > 1:
-        from repro.kernels import ops
+    from repro.kernels import ops
+    if ops.use_kernels() and T > 1:
         out, S = ops.rwkv6_scan(r, k, v, w, u, state["wkv"])
     elif _use_chunked() and T > 1:
         out, S = _wkv_chunked(r, k, v, w, u, state["wkv"],

@@ -278,7 +278,7 @@ class GSIServingEngine:
                  page_size: int = 16, num_pages: int = 0,
                  prefix_cache: bool = True, decode_publish: bool = True,
                  kv_dtype: Optional[str] = None,
-                 quantize_draft: bool = False, mesh=None):
+                 quantize_draft: bool = False, mesh=None, device=None):
         """Build the three models and jit the engine's serving phases.
 
         ``mesh`` (a ``jax.sharding.Mesh`` with a ``model`` axis — usually
@@ -313,6 +313,10 @@ class GSIServingEngine:
         (dequant fused into the paged-attention kernel).
         ``quantize_draft`` rounds the draft model's matmul weights
         through int8 at load (serving/quant.py).
+
+        ``device`` (without ``mesh``) pins the engine to one device: its
+        params and every state it creates live there, so replicas of a
+        fleet can each own a chip.  ``None`` leaves placement to JAX.
         """
         assert prm_cfg.reward_head
         quant.validate_kv_dtype(kv_dtype)
@@ -365,6 +369,9 @@ class GSIServingEngine:
                                  and self._prefix_supported())
         self.decode_publish = bool(decode_publish and self.prefix_cache)
         self.mesh = mesh
+        self.device = None if mesh is not None else device
+        if self.device is not None:
+            self.params = jax.device_put(self.params, self.device)
         self.tp = 1
         self._tp_plan = {"attn": False, "mlp": False, "vocab": False}
         if mesh is not None:
@@ -405,15 +412,14 @@ class GSIServingEngine:
             self._jit_admit = self._jit_extend = None
             self._jit_draft_phase = self._jit_target_phase = None
         else:
-            self._jit_step = jax.jit(self._bind(self._decode_core))
-            self._jit_commit = jax.jit(self._bind(self._commit))
-            self._jit_admit = jax.jit(self._bind(self._admit))
-            self._jit_extend = jax.jit(self._bind(self._extend))
+            self._jit_step = self._bind(self._decode_core)
+            self._jit_commit = self._bind(self._commit)
+            self._jit_admit = self._bind(self._admit)
+            self._jit_extend = self._bind(self._extend)
             # standalone phase jits: not on the decode path (the fused
             # _decode_core is), kept for phase-level tests and debugging
-            self._jit_draft_phase = jax.jit(self._bind(self._draft_phase))
-            self._jit_target_phase = jax.jit(
-                self._bind(self._target_phase))
+            self._jit_draft_phase = self._bind(self._draft_phase)
+            self._jit_target_phase = self._bind(self._target_phase)
         # host-side mirrors of per-slot bookkeeping, updated at admit /
         # materialize time: dispatch_decode assigns pages from these (a
         # read of the live device state would block on the in-flight
@@ -423,15 +429,18 @@ class GSIServingEngine:
         self._inflight_steps = 0      # dispatched but not yet materialized
 
     def _bind(self, phase):
-        """Close a params-threading phase over ``self.params``.
+        """Jit a params-threading phase and call it on ``self.params``.
 
         The phases take the three param trees as an explicit first
-        argument (so the mesh mode can hand shard_map their shardings);
-        the single-device jits bind the engine's own params here, which
-        keeps the jitted attributes' call signature ``(state, ...)``.
+        argument, and the jitted function receives them as arguments:
+        arrays a jitted function closes over would be embedded in the
+        program as constants (gigabytes at published widths).  The
+        bound call keeps the signature ``(state, ...)``.
         """
+        jitted = jax.jit(phase)
+
         def call(state, *extra):
-            return phase(self.params, state, *extra)
+            return jitted(self.params, state, *extra)
         return call
 
     def _build_mesh_jits(self, state) -> None:
@@ -461,10 +470,12 @@ class GSIServingEngine:
             def body(params, st, *extra):
                 with dtp.tensor_parallel("model"):
                     return phase(params, st, *extra)
-            sm = dtp.shard_map_compat(
+            # replication checking off: the bodies mix sharded and
+            # replicated leaves freely
+            sm = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(pspecs, state_specs) + (R,) * n_extra,
-                out_specs=out_specs)
+                out_specs=out_specs, check_vma=False)
             jitted = jax.jit(sm)
 
             def call(st, *extra):
@@ -554,9 +565,11 @@ class GSIServingEngine:
         """Mesh mode: place a fresh state on the replica's submesh (the
         target's KV leaves sharded over the kv-head axis, everything
         else replicated) and build the shard_map'd phase jits against
-        its structure.  Identity on single-device engines."""
+        its structure.  Single-device engines put the state on their
+        ``device`` (identity when they have none)."""
         if self.mesh is None:
-            return state
+            return state if self.device is None \
+                else jax.device_put(state, self.device)
         specs = serve_state_pspecs(state, self.mesh,
                                    shard_attn=self._tp_plan["attn"])
         state = jax.device_put(state, as_shardings(specs, self.mesh))

@@ -77,3 +77,74 @@ def test_ops_dispatch_interpret(monkeypatch):
     np.testing.assert_allclose(
         ops.logprob_gather(h, w, lab, 256),
         ref.logprob_gather_ref(h, w, lab, 256), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("name", ["flash_attention", "rwkv6_scan"])
+def test_kernel_gradient_is_the_references(monkeypatch, name):
+    """Pallas calls have no reverse-mode rule: ops wraps the kernels that
+    training differentiates so that their cotangents are the reference's."""
+    monkeypatch.setenv("REPRO_USE_PALLAS", "interpret")
+    from repro.kernels import ops
+    ks = jax.random.split(jax.random.PRNGKey(7), 7)
+    if name == "flash_attention":
+        args = (jax.random.normal(ks[0], (2, 24, 4, 16)),
+                jax.random.normal(ks[1], (2, 24, 2, 16)),
+                jax.random.normal(ks[2], (2, 24, 2, 16)))
+        fn, want_fn = ops.flash_attention, ref.flash_attention_ref
+    else:
+        B, T, H, hd = 1, 12, 2, 8
+        r, k, v = (jax.random.normal(ks[i], (B, T, H, hd)) for i in range(3))
+        w = jax.nn.sigmoid(jax.random.normal(ks[3], (B, T, H, hd))) * 0.5 \
+            + 0.45
+        args = (r, k, v, w, jax.random.normal(ks[4], (H, hd)) * 0.3,
+                jax.random.normal(ks[5], (B, H, hd, hd)) * 0.1)
+        fn, want_fn = ops.rwkv6_scan, ref.rwkv6_scan_ref
+
+    def loss(f):
+        def go(*a):
+            outs = jax.tree.leaves(f(*a))
+            return sum(jnp.sum(o * jnp.cos(jnp.arange(o.size).reshape(
+                o.shape))) for o in outs)
+        return go
+
+    argnums = tuple(range(len(args)))
+    assert "pallas_call" in str(jax.make_jaxpr(
+        jax.grad(loss(fn), argnums))(*args))
+    got = jax.grad(loss(fn), argnums)(*args)
+    want = jax.grad(loss(want_fn), argnums)(*args)
+    for g, w_ in zip(got, want):
+        np.testing.assert_allclose(g, w_, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("family", ["dense", "ssm"])
+def test_trainer_step_through_kernels(monkeypatch, family):
+    """A Trainer step differentiates through flash attention / the RWKV6
+    scan in kernel mode and agrees with the reference path."""
+    import dataclasses
+
+    from repro.config import ModelConfig, TrainConfig
+    from repro.train.trainer import Trainer
+    cfg = ModelConfig(name=f"tiny-{family}", family=family, num_layers=2,
+                      d_model=64, num_heads=4, num_kv_heads=2, d_ff=128,
+                      vocab_size=64, head_dim=16, dtype="float32",
+                      param_dtype="float32")
+    if family == "ssm":
+        cfg = dataclasses.replace(cfg, num_kv_heads=4, rwkv_head_dim=16)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (2, 16),
+                                           0, 64))
+    batch = {"tokens": tokens, "loss_mask": np.ones((2, 16), np.float32)}
+    hist = {}
+    for mode in ("interpret", "0"):
+        monkeypatch.setenv("REPRO_USE_PALLAS", mode)
+        tr = Trainer(cfg, TrainConfig(seed=0))
+        if mode == "interpret":
+            jaxpr = jax.make_jaxpr(tr._step)(
+                tr.params, tr.opt_state,
+                {k: jnp.asarray(v) for k, v in batch.items()})
+            assert "pallas_call" in str(jaxpr)
+        hist[mode] = tr.fit([batch], steps=1)[0]
+    assert np.isfinite(hist["interpret"]["loss"])
+    np.testing.assert_allclose(hist["interpret"]["loss"], hist["0"]["loss"],
+                               rtol=1e-5)
+    np.testing.assert_allclose(hist["interpret"]["grad_norm"],
+                               hist["0"]["grad_norm"], rtol=1e-4)

@@ -95,7 +95,8 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     from repro.optim import AdamW
     from repro.train import make_train_step
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_test_mesh
+    mesh = make_test_mesh((4, 2))
     # MoE arch exercises the shard_map expert-parallel path for real
     cfg = reduced_config(get_config("qwen2-moe-a2.7b"))
     model = build_model(cfg)
@@ -306,3 +307,44 @@ def test_mesh_single_device_engine_matches_unsharded(tiny_dense):
     assert pool.page_bytes > 0  # bytes-weighted LRU armed in production
     assert pool.num_free + pool.num_referenced + pool.num_cached \
         == eng.num_pages
+
+
+def test_tensor_parallel_context_is_per_thread():
+    """The fleet loop traces each replica's shard_map body on its own
+    thread: one replica leaving its tensor-parallel context must not end
+    another's, nor leak into an unsharded trace on a third thread."""
+    import threading
+    from repro.distributed import tp as dtp
+
+    errors = []
+    start = threading.Barrier(24)
+
+    def sharded():
+        start.wait(timeout=30)
+        for _ in range(300):
+            with dtp.tensor_parallel("model"):
+                for _ in range(5):
+                    if dtp.axis() != "model":
+                        errors.append("lost")
+            if dtp.axis() is not None:
+                errors.append("leaked")
+
+    def unsharded():
+        start.wait(timeout=30)
+        for _ in range(1500):
+            if dtp.axis() is not None:
+                errors.append("foreign")
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=sharded if i % 2 else unsharded)
+                   for i in range(24)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[:5]
