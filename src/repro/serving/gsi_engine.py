@@ -53,6 +53,7 @@ from repro.serving.engine import (branch_cache, branch_pages,
                                   take_candidates, take_per_request)
 from repro.serving.pages import PagePool, RadixIndex, pages_for
 from repro.serving.slots import pack_tails
+from repro.serving.spans import HostClock
 
 PAD = 0
 
@@ -79,6 +80,7 @@ class StepResult(NamedTuple):
     rewards: Optional[np.ndarray] = None      # (B, n) PRM rewards
     tilted: Optional[np.ndarray] = None       # (B, n) tilted rewards (gsi)
     logp_ratio: Optional[np.ndarray] = None   # (B, n) log pi_B - log pi_S
+    fallback: bool = False   # the rejection-fallback target phase ran
 
 
 class StepTicket(NamedTuple):
@@ -105,6 +107,7 @@ class StepTicket(NamedTuple):
     rewards: Optional[jax.Array]
     tilted: Optional[jax.Array]
     logp_ratio: Optional[jax.Array]
+    fallback: jax.Array              # () bool: the fallback branch ran
 
 
 @dataclass
@@ -148,6 +151,14 @@ class EngineStats:
     # largest prompt-token count committed by a single jitted admit/extend
     # call — the decode-stall proxy chunked prefill bounds (merged with max)
     prefill_commit_max: int = 0
+    # engine steps whose rejection-fallback target phase ran (the device
+    # cond's own predicate: some row, live or not, rejected)
+    fallback_steps: int = 0
+    # admissions, and their summed queue wait: scheduler clock at the
+    # admission call minus the request's queue entry (its arrival, its
+    # submission if later, or its re-queue after a preemption)
+    admissions: int = 0
+    queue_wait_s: float = 0.0
     # per-step trace arrays are bounded: at most ``trace_limit`` arrays are
     # retained per trace, while running moments keep exact aggregate
     # mean/variance for arbitrarily long serving runs (collect_stats=True
@@ -239,7 +250,8 @@ def merge_engine_stats(parts: Sequence[EngineStats]) -> EngineStats:
                 "prefix_hits", "prefix_hit_tokens", "prefix_pages_reused",
                 "prefill_tokens", "pages_evicted",
                 "decode_pages_published", "preemptions",
-                "resumes", "deadline_misses")
+                "resumes", "deadline_misses", "fallback_steps",
+                "admissions", "queue_wait_s")
     for p in parts:
         with p._lock:
             for f in counters:
@@ -427,6 +439,8 @@ class GSIServingEngine:
         self._known_pos = np.zeros((0,), np.int64)
         self._known_done = np.zeros((0,), bool)
         self._inflight_steps = 0      # dispatched but not yet materialized
+        # host time of dispatch's page assignment (span engine.assign_pages)
+        self.clock = HostClock()
 
     def _bind(self, phase):
         """Jit a params-threading phase and call it on ``self.params``.
@@ -868,20 +882,26 @@ class GSIServingEngine:
     # Jitted phases
     # ------------------------------------------------------------------
     def _commit(self, params, state, step_tokens, row_live=None):
-        """Append step_tokens (B,L) to the three committed caches."""
+        """Append step_tokens (B,L) to the three committed caches.
+
+        Each model's append runs under its own scope (``draft``,
+        ``target``, ``prm``); callers open ``gsi.commit`` around it."""
         ps, pb, pp = params
         caches = state["caches"]
         pt = state.get("pt")
         new = {}
-        _, new["S"], pos = score_and_append(
-            self.draft, ps, caches["S"], state["pending"], state["pos"],
-            step_tokens, row_live=row_live, pt=pt)
-        _, new["B"], _ = score_and_append(
-            self.target, pb, caches["B"], state["pending"], state["pos"],
-            step_tokens, row_live=row_live, pt=pt)
-        _, new["P"], _, _ = score_and_append(
-            self.prm, pp, caches["P"], state["pending"], state["pos"],
-            step_tokens, return_rewards=True, row_live=row_live, pt=pt)
+        with jax.named_scope("draft"):
+            _, new["S"], pos = score_and_append(
+                self.draft, ps, caches["S"], state["pending"],
+                state["pos"], step_tokens, row_live=row_live, pt=pt)
+        with jax.named_scope("target"):
+            _, new["B"], _ = score_and_append(
+                self.target, pb, caches["B"], state["pending"],
+                state["pos"], step_tokens, row_live=row_live, pt=pt)
+        with jax.named_scope("prm"):
+            _, new["P"], _, _ = score_and_append(
+                self.prm, pp, caches["P"], state["pending"], state["pos"],
+                step_tokens, return_rewards=True, row_live=row_live, pt=pt)
         length = jnp.sum(step_tokens != PAD, axis=1)
         if row_live is not None:
             length = jnp.where(row_live, length, 0)
@@ -918,18 +938,21 @@ class GSIServingEngine:
         decode masks) until :meth:`extend` commits its final chunk, so live
         neighbours keep decoding while the long prompt trickles in.
         """
-        caches = reset_cache_rows(state["caches"], admit_mask)
-        new = {
-            "caches": caches,
-            "pending": jnp.where(admit_mask, tails[:, 0],
-                                 state["pending"]),
-            "pos": jnp.where(admit_mask, starts, state["pos"]),
-            "done": jnp.where(admit_mask, ~live, state["done"]),
-        }
-        if "pt" in state:
-            new["pt"], new["scratch"] = state["pt"], state["scratch"]
-            new["gen"] = state["gen"]
-        return self._commit(params, new, tails[:, 1:], row_live=admit_mask)
+        with jax.named_scope("gsi.admit"):
+            caches = reset_cache_rows(state["caches"], admit_mask)
+            new = {
+                "caches": caches,
+                "pending": jnp.where(admit_mask, tails[:, 0],
+                                     state["pending"]),
+                "pos": jnp.where(admit_mask, starts, state["pos"]),
+                "done": jnp.where(admit_mask, ~live, state["done"]),
+            }
+            if "pt" in state:
+                new["pt"], new["scratch"] = state["pt"], state["scratch"]
+                new["gen"] = state["gen"]
+            with jax.named_scope("gsi.commit"):
+                return self._commit(params, new, tails[:, 1:],
+                                    row_live=admit_mask)
 
     def _extend(self, params, state, mask, chunks, live):
         """Commit continuation prefill ``chunks`` (B,W; PAD-padded) into
@@ -943,8 +966,10 @@ class GSIServingEngine:
         ``live`` flips rows whose final chunk this is to done=False; rows
         mid-prefill stay device-done and inert under the decode masks.
         """
-        new = self._commit(params, state, chunks, row_live=mask)
-        new["done"] = jnp.where(mask, ~live, state["done"])
+        with jax.named_scope("gsi.extend"):
+            with jax.named_scope("gsi.commit"):
+                new = self._commit(params, state, chunks, row_live=mask)
+            new["done"] = jnp.where(mask, ~live, state["done"])
         return new
 
     def _branch(self, cache, n, state):
@@ -958,79 +983,92 @@ class GSIServingEngine:
                             self.page_size), bpt
 
     def _draft_phase(self, params, state, rng):
-        """Sample n draft candidates; score with target + PRM."""
+        """Sample n draft candidates; score with target + PRM.
+
+        Scoped ``gsi.draft`` (branching and sampling), ``gsi.verify``
+        (the candidates' scoring, children ``prm`` and ``target``) and
+        ``gsi.select`` (the decision and the chosen step)."""
         g = self.gcfg
         n = g.n
         ps, pb, pp = params
-        k1, k2 = jax.random.split(rng)
-        pend = expand_requests(state["pending"], n)
-        pos = expand_requests(state["pos"], n)
-        done = expand_requests(state["done"], n)
+        with jax.named_scope("gsi.draft"):
+            k1, k2 = jax.random.split(rng)
+            pend = expand_requests(state["pending"], n)
+            pos = expand_requests(state["pos"], n)
+            done = expand_requests(state["done"], n)
 
-        scratch_s, bpt = self._branch(state["caches"]["S"], n, state)
-        steps = sample_steps(
-            self.draft, ps, scratch_s, pend, pos, k1,
-            max_tokens=g.max_step_tokens, sep_token=g.sep_token_id,
-            eos_token=g.eos_token_id, temperature=g.temperature,
-            top_p=g.top_p, already_done=done, pt=bpt)
+            scratch_s, bpt = self._branch(state["caches"]["S"], n, state)
+            steps = sample_steps(
+                self.draft, ps, scratch_s, pend, pos, k1,
+                max_tokens=g.max_step_tokens, sep_token=g.sep_token_id,
+                eos_token=g.eos_token_id, temperature=g.temperature,
+                top_p=g.top_p, already_done=done, pt=bpt)
+            cands = fold_candidates(steps.tokens, n)         # (B,n,L)
+            out = {
+                "cands": cands,
+                "logp_S": fold_candidates(steps.logprob, n),  # (B,n)
+                "rng": k2,
+            }
 
-        cands = fold_candidates(steps.tokens, n)             # (B,n,L)
-        # PRM rewards (always needed)
-        if self.shared_scoring:
-            from repro.models.scoring import score_candidates
-            cache_p = state["caches"]["P"]
-            if self.paged:
-                cache_p = paged_view(cache_p, state["pt"])
-            _, rewards = score_candidates(
-                self.prm, pp, cache_p, state["pending"],
-                state["pos"], cands, return_rewards=True)
-        else:
-            scratch_p, _ = self._branch(state["caches"]["P"], n, state)
-            _, _, _, rewards_flat = score_and_append(
-                self.prm, pp, scratch_p, pend, pos, steps.tokens,
-                return_rewards=True, pt=bpt)
-            rewards = fold_candidates(rewards_flat, n)
-
-        out = {
-            "cands": cands,
-            "logp_S": fold_candidates(steps.logprob, n),     # (B,n)
-            "rewards": rewards,
-            "rng": k2,
-        }
-        if self.mode in ("gsi", "gsi_norej"):
+        with jax.named_scope("gsi.verify"), jax.named_scope("prm"):
+            # PRM rewards (always needed)
             if self.shared_scoring:
                 from repro.models.scoring import score_candidates
-                cache_b = state["caches"]["B"]
+                cache_p = state["caches"]["P"]
                 if self.paged:
-                    cache_b = paged_view(cache_b, state["pt"])
-                out["logp_B"] = score_candidates(
-                    self.target, pb, cache_b,
-                    state["pending"], state["pos"], cands)
+                    cache_p = paged_view(cache_p, state["pt"])
+                _, out["rewards"] = score_candidates(
+                    self.prm, pp, cache_p, state["pending"],
+                    state["pos"], cands, return_rewards=True)
             else:
-                scratch_b, _ = self._branch(state["caches"]["B"], n, state)
-                logp_B, _, _ = score_and_append(
-                    self.target, pb, scratch_b, pend, pos, steps.tokens,
-                    pt=bpt)
-                out["logp_B"] = fold_candidates(logp_B, n)
-            dec = gsi_select(k2, out["rewards"], out["logp_B"],
-                             out["logp_S"], beta=g.beta,
-                             threshold_u=g.threshold_u)
-            accept = dec.accept if (self.mode == "gsi" and g.use_rejection) \
-                else jnp.ones_like(dec.accept)
-            out.update(index=dec.index, accept=accept,
-                       selected=dec.selected_tilted, tilted=dec.tilted)
-        elif self.mode == "rsd":
-            dec = rsd_select(k2, out["rewards"], beta=g.beta,
-                             threshold=self.rsd_threshold)
-            out.update(index=dec.index, accept=dec.accept,
-                       selected=dec.selected_reward, tilted=out["rewards"])
-        else:  # sbon_s: always accept the soft-BoN choice
-            idx = soft_bon_select(k2, out["rewards"], g.beta)
-            out.update(index=idx, accept=jnp.ones((idx.shape[0],), bool),
-                       selected=take_per_request(out["rewards"], idx),
-                       tilted=out["rewards"])
-        out["chosen"] = take_candidates(out["cands"], out["index"])
-        out["max_reward"] = jnp.max(out["rewards"], axis=-1)
+                scratch_p, _ = self._branch(state["caches"]["P"], n, state)
+                _, _, _, rewards_flat = score_and_append(
+                    self.prm, pp, scratch_p, pend, pos, steps.tokens,
+                    return_rewards=True, pt=bpt)
+                out["rewards"] = fold_candidates(rewards_flat, n)
+        tilted_modes = self.mode in ("gsi", "gsi_norej")
+        if tilted_modes:
+            with jax.named_scope("gsi.verify"), jax.named_scope("target"):
+                if self.shared_scoring:
+                    from repro.models.scoring import score_candidates
+                    cache_b = state["caches"]["B"]
+                    if self.paged:
+                        cache_b = paged_view(cache_b, state["pt"])
+                    out["logp_B"] = score_candidates(
+                        self.target, pb, cache_b,
+                        state["pending"], state["pos"], cands)
+                else:
+                    scratch_b, _ = self._branch(state["caches"]["B"], n,
+                                                state)
+                    logp_B, _, _ = score_and_append(
+                        self.target, pb, scratch_b, pend, pos,
+                        steps.tokens, pt=bpt)
+                    out["logp_B"] = fold_candidates(logp_B, n)
+
+        with jax.named_scope("gsi.select"):
+            if tilted_modes:
+                dec = gsi_select(k2, out["rewards"], out["logp_B"],
+                                 out["logp_S"], beta=g.beta,
+                                 threshold_u=g.threshold_u)
+                accept = dec.accept if (self.mode == "gsi"
+                                        and g.use_rejection) \
+                    else jnp.ones_like(dec.accept)
+                out.update(index=dec.index, accept=accept,
+                           selected=dec.selected_tilted, tilted=dec.tilted)
+            elif self.mode == "rsd":
+                dec = rsd_select(k2, out["rewards"], beta=g.beta,
+                                 threshold=self.rsd_threshold)
+                out.update(index=dec.index, accept=dec.accept,
+                           selected=dec.selected_reward,
+                           tilted=out["rewards"])
+            else:  # sbon_s: always accept the soft-BoN choice
+                idx = soft_bon_select(k2, out["rewards"], g.beta)
+                out.update(index=idx,
+                           accept=jnp.ones((idx.shape[0],), bool),
+                           selected=take_per_request(out["rewards"], idx),
+                           tilted=out["rewards"])
+            out["chosen"] = take_candidates(out["cands"], out["index"])
+            out["max_reward"] = jnp.max(out["rewards"], axis=-1)
         return out
 
     def _target_phase(self, params, state, rng):
@@ -1072,50 +1110,66 @@ class GSIServingEngine:
         to skipping it), commit, and the EOS / B.2 done fold.  Returns
         ``(new_state, StepTicket)`` — everything a pipelined caller needs
         without a host round-trip.
+
+        Every phase runs under a named scope, which reaches the compiled
+        operations' metadata and so the profiler's trace: ``gsi.draft``,
+        ``gsi.verify``, ``gsi.select`` (``_draft_phase``),
+        ``gsi.fallback`` inside the cond, ``gsi.commit`` (children
+        ``draft``, ``target``, ``prm``); ``gsi.target`` in sbon_b mode.
         """
         g = self.gcfg
         if self.mode == "sbon_b":
-            tp = self._target_phase(params, state, rng)
-            chosen = tp["chosen"]
-            accept = jnp.ones_like(state["done"])
-            max_r = jnp.max(tp["rewards"], axis=-1)
-            draft_count = jnp.zeros((), jnp.int32)
-            target_count = jnp.sum(tp["cands"] != PAD).astype(jnp.int32)
+            with jax.named_scope("gsi.target"):
+                tp = self._target_phase(params, state, rng)
+                chosen = tp["chosen"]
+                accept = jnp.ones_like(state["done"])
+                max_r = jnp.max(tp["rewards"], axis=-1)
+                draft_count = jnp.zeros((), jnp.int32)
+                target_count = jnp.sum(tp["cands"] != PAD).astype(jnp.int32)
             rewards = tilted = ratio = None
+            all_accept = jnp.ones((), bool)
+            ran_fallback = jnp.zeros((), bool)
         else:
             dp = self._draft_phase(params, state, rng)
             accept = dp["accept"]
             max_r = dp["max_reward"]
-            draft_count = jnp.sum(dp["cands"] != PAD).astype(jnp.int32)
             rewards = dp["rewards"]
             tilted = dp["tilted"] if "logp_B" in dp else None
-            ratio = (dp["logp_B"] - dp["logp_S"]) if "logp_B" in dp \
-                else None
+            with jax.named_scope("gsi.select"):
+                draft_count = jnp.sum(dp["cands"] != PAD).astype(jnp.int32)
+                ratio = (dp["logp_B"] - dp["logp_S"]) if "logp_B" in dp \
+                    else None
+                all_accept = jnp.all(accept)
+                ran_fallback = ~all_accept
 
             def fallback(_):
-                tp = self._target_phase(params, state, rng_target)
-                return (tp["chosen"],
-                        jnp.sum(tp["cands"] != PAD).astype(jnp.int32))
+                with jax.named_scope("gsi.fallback"):
+                    tp = self._target_phase(params, state, rng_target)
+                    return (tp["chosen"],
+                            jnp.sum(tp["cands"] != PAD).astype(jnp.int32))
 
             def no_fallback(_):
                 return (jnp.zeros_like(dp["chosen"]),
                         jnp.zeros((), jnp.int32))
 
             tp_chosen, target_count = jax.lax.cond(
-                jnp.all(accept), no_fallback, fallback, None)
-            chosen = jnp.where(accept[:, None], dp["chosen"], tp_chosen)
-        done_prev = state["done"]
-        # early stop (paper B.2): all draft rewards below min threshold
-        failed = max_r < g.min_step_reward
-        new_state = self._commit(params, state, chosen)
-        eos = jnp.any(chosen == g.eos_token_id, axis=1)
-        new_done = done_prev | eos | (failed & ~done_prev)
-        new_state["done"] = new_done
+                all_accept, no_fallback, fallback, None)
+        with jax.named_scope("gsi.commit"):
+            if self.mode != "sbon_b":
+                chosen = jnp.where(accept[:, None], dp["chosen"], tp_chosen)
+            done_prev = state["done"]
+            # early stop (paper B.2): all draft rewards below min threshold
+            failed = max_r < g.min_step_reward
+            new_state = self._commit(params, state, chosen)
+            eos = jnp.any(chosen == g.eos_token_id, axis=1)
+            new_done = done_prev | eos | (failed & ~done_prev)
+            new_state["done"] = new_done
         ticket = StepTicket(
             chosen=chosen, done_prev=done_prev, eos=eos, failed=failed,
             accept=accept, done=new_done, pos=new_state["pos"],
             draft_tokens=draft_count, target_tokens=target_count,
-            rewards=rewards, tilted=tilted, logp_ratio=ratio)
+            rewards=rewards, tilted=tilted, logp_ratio=ratio,
+            fallback=ran_fallback)
         return new_state, ticket
 
     def dispatch_decode(self, state, rng, rng_target=None):
@@ -1136,7 +1190,8 @@ class GSIServingEngine:
             # page in the blocks every in-flight step may write: one
             # max_step_tokens of look-ahead per dispatched-unharvested step
             ahead = (self._inflight_steps + 1) * g.max_step_tokens
-            state = self._assign_pages(state, ahead)
+            with self.clock.span("engine.assign_pages"):
+                state = self._assign_pages(state, ahead)
         new_state, ticket = self._jit_step(state, rng, rng_target)
         new_state = self._with_gen(new_state, state)
         self._inflight_steps += 1
@@ -1157,6 +1212,7 @@ class GSIServingEngine:
         kw = {n: host.get(n) for n in StepTicket._fields}
         kw["draft_tokens"] = int(kw["draft_tokens"])
         kw["target_tokens"] = int(kw["target_tokens"])
+        kw["fallback"] = bool(kw["fallback"])
         self._known_pos = np.array(kw["pos"], np.int64)
         self._known_done = np.array(kw["done"], bool)
         self._inflight_steps = max(0, self._inflight_steps - 1)
@@ -1177,7 +1233,8 @@ class GSIServingEngine:
         stats.bump(steps=1, draft_tokens=res.draft_tokens,
                    target_tokens=res.target_tokens,
                    decisions=int(live.sum()),
-                   accepted=int((res.accept & live).sum()))
+                   accepted=int((res.accept & live).sum()),
+                   fallback_steps=int(res.fallback))
         if collect_stats:
             stats.record_trace("raw_rewards", res.rewards)
             if res.logp_ratio is not None:

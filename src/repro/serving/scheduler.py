@@ -75,6 +75,7 @@ import numpy as np
 from repro.serving.gsi_engine import (EngineStats, GSIServingEngine,
                                       StepResult, StepTicket)
 from repro.serving.slots import PAD, SlotPool, pack_prompts
+from repro.serving.spans import HostClock
 
 
 @dataclass
@@ -88,6 +89,8 @@ class Request:
     submitted_at: float = 0.0     # wall clock (perf_counter) at submit
     priority: int = 0             # larger = more urgent (0 = default class)
     deadline_s: Optional[float] = None   # SLO: finish within s of arrival
+    queued_at: float = 0.0        # scheduler clock when it (re-)entered
+                                  # the queue: where its queue wait starts
 
 
 @dataclass
@@ -336,11 +339,12 @@ class GSIScheduler:
         self._retired: Optional[_RetiredStep] = None
         # idle handling: woken by submit(), waits out exact arrival gaps
         self._wake = threading.Condition()
-        # host/device overlap accounting (pipeline_stats)
-        self._overlap_host_s = 0.0       # host work under an in-flight step
-        self._serial_host_s = 0.0        # host work with the device idle
-        self._materialize_wait_s = 0.0   # blocked waiting on device results
-        self._dispatch_s = 0.0           # enqueueing steps (incl. compiles)
+        # host/device overlap accounting (pipeline_stats), one profiler
+        # span per timed region: overlap_host_s (host work under an
+        # in-flight step), serial_host_s (host work with the device idle),
+        # materialize_wait_s (blocked on device results), dispatch_s
+        # (enqueueing steps, compiles included)
+        self._clock = HostClock()
 
     def fresh_state(self) -> None:
         """Reset for a new serving phase (back-to-back benchmark runs).
@@ -372,10 +376,7 @@ class GSIScheduler:
         self._head_bypassed = 0
         self._inflight = None
         self._retired = None
-        self._overlap_host_s = 0.0
-        self._serial_host_s = 0.0
-        self._materialize_wait_s = 0.0
-        self._dispatch_s = 0.0
+        self._clock = HostClock()
 
     # ------------------------------------------------------------------
     # Submission / admission control
@@ -432,11 +433,17 @@ class GSIScheduler:
         if stream is not None:
             self._streams[request_id] = stream
         self._seq += 1
+        # a request submitted once the scheduler clock runs waits from
+        # its submission at the earliest (closed-loop clients submit
+        # with arrival_time 0); before that, from its arrival_time
+        queued_at = float(arrival_time) if self._t0 is None \
+            else max(float(arrival_time), self._now())
         self.queue.append(Request(
             id=request_id, prompt=prompt, max_steps=budget,
             arrival_time=float(arrival_time),
             submitted_at=time.perf_counter(),
-            priority=int(priority), deadline_s=deadline_s))
+            priority=int(priority), deadline_s=deadline_s,
+            queued_at=queued_at))
         if len(self.queue) > 1 and \
                 arrival_time < self.queue[-2].arrival_time:
             # keep the queue arrival-ordered (stable for equal arrivals) so
@@ -520,7 +527,12 @@ class GSIScheduler:
         one per-step prefill token budget: a prompt whose tail exceeds
         what is left admits *truncated* (its slot inert until ``extend``
         commits the rest, one chunk per step).
+
+        ``now`` decides which requests have arrived; the scheduler clock
+        read on entry stamps the admissions (``Response.admitted_at``)
+        and ends their queue wait (``stats.queue_wait_s``).
         """
+        at = self._now()
         budget = self._chunk if self._chunk else None
         budget = self._advance_prefill(now, budget)
         if not self.continuous and self.pool.num_live > 0:
@@ -600,10 +612,12 @@ class GSIScheduler:
                 self.stats.bump(resumes=1)   # resumed after a preemption
             else:
                 resp = Response(
-                    request_id=req.id, admitted_at=now,
+                    request_id=req.id, admitted_at=at,
                     arrival_time=req.arrival_time,
                     priority=req.priority, deadline_s=req.deadline_s)
             self._partial[slot] = resp
+        self.stats.bump(admissions=len(batch), queue_wait_s=sum(
+            at - req.queued_at for req, _ in batch.values()))
         self.state = self.engine.admit(self.state, mask, packed, starts,
                                        live=live)
         self.stats.prefill_commit_max = max(
@@ -770,7 +784,8 @@ class GSIScheduler:
         resumed = Request(
             id=req.id, prompt=context, max_steps=remaining,
             arrival_time=req.arrival_time, submitted_at=req.submitted_at,
-            priority=req.priority, deadline_s=req.deadline_s)
+            priority=req.priority, deadline_s=req.deadline_s,
+            queued_at=self._now())
         self.queue.appendleft(resumed)
         if len(self.queue) > 1 and \
                 self.queue[1].arrival_time < resumed.arrival_time:
@@ -937,27 +952,21 @@ class GSIScheduler:
            synchronous scheduler would see before this engine step.
         """
         finished: List[Response] = []
-        t0 = time.perf_counter()
-        overlapped = self._inflight is not None
+        clock = self._clock
         if self._retired is not None:
             retired, self._retired = self._retired, None
-            finished = self._harvest(retired)
-        t1 = time.perf_counter()
-        if overlapped:
-            self._overlap_host_s += t1 - t0
-        else:
-            self._serial_host_s += t1 - t0
+            key = "serial_host_s" if self._inflight is None \
+                else "overlap_host_s"
+            with clock.span("sched.harvest", key):
+                finished = self._harvest(retired)
         if self._inflight is not None:
             pend, self._inflight = self._inflight, None
-            res = self.engine.materialize(pend.ticket)
-            t2 = time.perf_counter()
-            self._materialize_wait_s += t2 - t1
-            self._retire(pend, res)
+            with clock.span("sched.materialize", "materialize_wait_s"):
+                res = self.engine.materialize(pend.ticket)
+            with clock.span("sched.retire", "serial_host_s"):
+                self._retire(pend, res)
+        with clock.span("sched.admit", "serial_host_s"):
             self._admit_ready(now)
-            self._serial_host_s += time.perf_counter() - t2
-        else:
-            self._admit_ready(now)
-            self._serial_host_s += time.perf_counter() - t1
         return finished
 
     def _retire(self, pend: _InflightStep, res: StepResult) -> None:
@@ -1027,30 +1036,29 @@ class GSIScheduler:
 
     def _dispatch(self, rng, rng_target=None) -> None:
         """Dispatch the next engine step and leave its ticket in flight."""
-        t0 = time.perf_counter()
-        self.state, ticket = self.engine.dispatch_decode(
-            self.state, rng, rng_target)
-        self.engine_steps += 1
-        self._inflight = _InflightStep(ticket=ticket,
-                                       bound=dict(self._partial))
-        self._dispatch_s += time.perf_counter() - t0
+        with self._clock.span("sched.dispatch", "dispatch_s"):
+            self.state, ticket = self.engine.dispatch_decode(
+                self.state, rng, rng_target)
+            self.engine_steps += 1
+            self._inflight = _InflightStep(ticket=ticket,
+                                           bound=dict(self._partial))
 
     def flush(self) -> List[Response]:
         """Drain the pipeline without dispatching: materialize the
         in-flight ticket (if any) and harvest everything retired.
         Returns the responses finalized by the drain."""
         finished: List[Response] = []
+        clock = self._clock
         if self._inflight is not None:
             pend, self._inflight = self._inflight, None
-            t0 = time.perf_counter()
-            res = self.engine.materialize(pend.ticket)
-            self._materialize_wait_s += time.perf_counter() - t0
-            self._retire(pend, res)
+            with clock.span("sched.materialize", "materialize_wait_s"):
+                res = self.engine.materialize(pend.ticket)
+            with clock.span("sched.retire", "serial_host_s"):
+                self._retire(pend, res)
         if self._retired is not None:
             retired, self._retired = self._retired, None
-            t0 = time.perf_counter()
-            finished = self._harvest(retired)
-            self._serial_host_s += time.perf_counter() - t0
+            with clock.span("sched.harvest", "serial_host_s"):
+                finished = self._harvest(retired)
         return finished
 
     def pipeline_stats(self) -> Dict[str, float]:
@@ -1063,15 +1071,16 @@ class GSIScheduler:
         purely synchronous scheduler.  ``materialize_wait_s`` is the
         time the host spent blocked on device results.
         """
-        total = self._overlap_host_s + self._serial_host_s
+        sec = self._clock.seconds
+        overlap, serial = sec["overlap_host_s"], sec["serial_host_s"]
+        total = overlap + serial
         return {
             "sync": self.sync,
-            "overlap_host_s": self._overlap_host_s,
-            "serial_host_s": self._serial_host_s,
-            "materialize_wait_s": self._materialize_wait_s,
-            "dispatch_s": self._dispatch_s,
-            "overlap_fraction":
-                self._overlap_host_s / total if total > 0 else 0.0,
+            "overlap_host_s": overlap,
+            "serial_host_s": serial,
+            "materialize_wait_s": sec["materialize_wait_s"],
+            "dispatch_s": sec["dispatch_s"],
+            "overlap_fraction": overlap / total if total > 0 else 0.0,
         }
 
     # ------------------------------------------------------------------
@@ -1090,6 +1099,9 @@ class GSIScheduler:
     def run(self, rng) -> Dict[str, Response]:
         """Drain the queue and all live slots; returns id -> Response."""
         self._t0 = time.perf_counter()
+        for req in self.queue:
+            # the clock restarts here: arrival times count from now on
+            req.queued_at = req.arrival_time
         if not self.sync:
             return self._run_async(rng)
         while self.queue or self.pool.num_live:
