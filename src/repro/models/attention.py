@@ -1,9 +1,11 @@
 """GQA attention: full / sliding-window (ring-buffer cache) / cross.
 
-Three execution modes per block:
+Four execution modes per block:
   * ``train``   — full-sequence causal attention, no cache.
   * ``prefill`` — same math, additionally returns the populated KV cache.
   * ``decode``  — single-token query against the cache (per-request positions).
+  * ``extend``  — a (B, W) block of tokens at per-token positions appended
+    to an existing cache in one pass (serving's prompt prefill).
 
 Local (sliding-window) layers keep a **ring buffer** cache of ``window``
 entries, so long_500k decode stores O(window), not O(seq), per local layer.
@@ -139,12 +141,15 @@ def _cache_len(cfg, kind: str, max_seq: int) -> int:
 
 def self_attention(cfg, p, x, *, kind: str, mode: str,
                    positions, cache=None, window_override: int = 0,
-                   max_seq: int = 0, causal: bool = True, pt=None):
+                   max_seq: int = 0, causal: bool = True, pt=None,
+                   live=None, ctx: int = 0):
     """Returns (out, new_cache).
 
-    positions: (S,) for train/prefill (shared across batch); (B,) for decode.
-    ``pt`` (B, nblk) selects the paged decode path when ``cache`` holds
-    page pools ({'kp','vp'}) instead of per-slot dense rows ({'k','v'}).
+    positions: (S,) for train/prefill (shared across batch); (B,) for
+    decode; (B, W) for extend, where ``live`` (B, W) marks the tokens to
+    append and ``ctx`` bounds the positions the block can reach.
+    ``pt`` (B, nblk) selects the paged path when ``cache`` holds page
+    pools ({'kp','vp'}) instead of per-slot dense rows ({'k','v'}).
     """
     B = x.shape[0]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -166,6 +171,11 @@ def self_attention(cfg, p, x, *, kind: str, mode: str,
         if mode == "prefill":
             new_cache = _fill_cache(cfg, kind, k, v, positions,
                                     max_seq or k.shape[1])
+    elif mode == "extend":  # x is (B,W,d), positions (B,W)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        out, new_cache = _extend_attention(cache, q, k, v, positions, live,
+                                           pt, ctx, window, scale)
     else:  # decode: x is (B,1,d), positions (B,)
         pos_b = positions[:, None]  # (B,1)
         q = apply_rope(q, pos_b, cfg.rope_theta)
@@ -180,7 +190,7 @@ def self_attention(cfg, p, x, *, kind: str, mode: str,
                     new_cache["vs"], pt, positions, window=window,
                     scale=scale)
             else:
-                new_cache = _write_cache_paged(cache, k, v, positions, pt)
+                new_cache = _write_cache_paged(cache, k, v, pos_b, pt)
                 out = ops.paged_attention(q, new_cache["kp"],
                                           new_cache["vp"], pt, positions,
                                           window=window, scale=scale)
@@ -239,24 +249,29 @@ def _write_cache(cache, k, v, positions):
     return {"k": k_new, "v": v_new}
 
 
-def _write_cache_paged(cache, k, v, positions, pt):
-    """Write the new (B,1,KV,hd) kv through the block table.
+def _write_cache_paged(cache, k, v, positions, pt, live=None):
+    """Write the new (B,W,KV,hd) kv at positions (B,W) through the block
+    table; decode writes W=1.
 
     Physical row of position p for request b is
     ``pt[b, p // ps] * ps + p % ps``.  Rows that are done (or never
     admitted) resolve to scratch/trash pages the host allocator set up, so
     the unconditional write stays harmless exactly as in the dense path.
+    ``live`` (B,W), if given, marks the tokens to write; the others are
+    dropped.
     """
     kp, vp = cache["kp"], cache["vp"]
     P, ps = kp.shape[0], kp.shape[1]
     blk = jnp.minimum(positions // ps, pt.shape[1] - 1)
-    page = jnp.take_along_axis(pt, blk[:, None], axis=1)[:, 0]
-    rows = page * ps + positions % ps                      # (B,)
+    rows = jnp.take_along_axis(pt, blk, axis=1) * ps + positions % ps
+    if live is not None:
+        rows = jnp.where(live, rows, P * ps)               # out of range
+    rows = rows.reshape(-1)                                # (B*W,)
 
     def upd(pool, new):
         flat = pool.reshape((P * ps,) + pool.shape[2:])
-        return flat.at[rows].set(
-            new[:, 0].astype(pool.dtype)).reshape(pool.shape)
+        new = new.reshape((-1,) + pool.shape[2:]).astype(pool.dtype)
+        return flat.at[rows].set(new, mode="drop").reshape(pool.shape)
 
     out = dict(cache)
     out["kp"], out["vp"] = upd(kp, k), upd(vp, v)
@@ -308,6 +323,60 @@ def _write_cache_paged_quant(cache, k, v, positions, pt):
     out["kp"], out["ks"] = upd(cache["kp"], cache["ks"], k)
     out["vp"], out["vs"] = upd(cache["vp"], cache["vs"], v)
     return out
+
+
+def _extend_attention(cache, q, k, v, positions, live, pt, ctx, window,
+                      scale):
+    """Append a block of tokens to the cache and attend causally, in one
+    pass over the whole block.
+
+    q/k/v: (B, W, ., hd) rope'd; positions (B, W) absolute, strictly
+    increasing over each row's ``live`` tokens, which are the only ones
+    written.  Each row's context is laid out by absolute position over
+    ``[0, ctx)``: what the cache held below the row's first position
+    (pages ``pt[b, :ctx // ps]``, or the dense row, a ring for window
+    layers) with the block's own keys put in at their positions, in the
+    pool's storage dtype, so every query sees the rows a token-serial
+    append would have written before it.  A ring row holds the last
+    ``size <= window`` positions, so the window mask alone hides the
+    positions it no longer holds.  Returns (out, new_cache).
+    """
+    B, W = positions.shape
+    bidx = jnp.broadcast_to(jnp.arange(B)[:, None], (B, W))
+    if pt is not None and "kp" in cache:
+        nb = ctx // cache["kp"].shape[1]
+
+        def view(pool):
+            return pool[pt[:, :nb]].reshape((B, ctx) + pool.shape[2:])
+        keys = ("kp", "vp")
+        out = _write_cache_paged(cache, k, v, positions, pt, live)
+    else:
+        size = cache["k"].shape[1]
+        # a ring row (window layers) holds the last ``size`` positions;
+        # of the block only those survive, so no two writes share a slot
+        end = positions[:, :1] + jnp.sum(live, axis=1, keepdims=True)
+        slots = jnp.where(live & (positions >= end - size),
+                          positions % size, size)
+
+        def view(row):
+            return jnp.take(row, jnp.arange(ctx) % size, axis=1)
+        def write(row, new):
+            return row.at[bidx, slots].set(new.astype(row.dtype),
+                                           mode="drop")
+        keys = ("k", "v")
+        out = dict(cache, k=write(cache["k"], k), v=write(cache["v"], v))
+    at = jnp.where(live, positions, ctx)
+    kv = []
+    for key, new in zip(keys, (k, v)):
+        ctx_rows = view(cache[key])
+        kv.append(ctx_rows.at[bidx, at].set(new.astype(ctx_rows.dtype),
+                                            mode="drop"))
+    kpos = jnp.arange(ctx)[None, None, :]
+    qpos = positions[:, :, None]
+    mask = kpos <= qpos
+    if window:
+        mask &= kpos > qpos - window
+    return gqa_attention(q, kv[0], kv[1], mask, scale), out
 
 
 def _decode_mask(sk: int, positions, *, ring: bool):

@@ -79,7 +79,11 @@ def _freeze(live, new, old):
 
 def block_apply(cfg, kind: str, p, x, *, mode: str, positions,
                 cache=None, source=None, max_seq: int = 0,
-                window_override: int = 0, live=None, pt=None):
+                window_override: int = 0, live=None, pt=None, ctx: int = 0):
+    if mode == "extend" and kind not in ("full", "local"):
+        # recurrent state advances token by token; a block append is
+        # defined for position-addressed attention K/V only
+        raise NotImplementedError(f"extend mode on a {kind!r} block")
     if kind == "rwkv":
         state = cache if cache is not None else rwkv.init_rwkv_state(
             cfg, x.shape[0])
@@ -111,7 +115,7 @@ def block_apply(cfg, kind: str, p, x, *, mode: str, positions,
                                      else kind),
             mode=mode, positions=positions, cache=self_cache,
             window_override=window_override, max_seq=max_seq,
-            causal=(kind != "enc"), pt=pt)
+            causal=(kind != "enc"), pt=pt, live=live, ctx=ctx)
     x = x + y
 
     if kind == "cross":

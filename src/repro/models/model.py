@@ -6,6 +6,8 @@
   * ``forward(params, tokens, source)``  — full-sequence logits (training)
   * ``prefill(params, tokens, ...)``     — last-token logits + KV cache
   * ``decode_step(params, cache, tok, pos)`` — one-token serving step
+  * ``extend(params, cache, tokens, start, live)`` — append a block of
+    tokens to the cache in one pass (serving's prompt prefill)
   * ``init_cache(batch, max_seq)``       — zeroed cache pytree
   * ``score(params, tokens, ...)``       — log p(tokens) per position (GSI)
   * ``reward(params, tokens, ...)``      — PRM head scores per position
@@ -99,7 +101,7 @@ class Model:
     # ------------------------------------------------------------------
     def _run_stack(self, params, x, *, mode, positions, cache=None,
                    source=None, max_seq=0, window_override=0, live=None,
-                   pt=None):
+                   pt=None, ctx=0):
         cfg = self.cfg
         aux_total = 0.0
         new_cache = {"blocks": None, "rem": None}
@@ -107,7 +109,7 @@ class Model:
         apply = functools.partial(
             B.block_apply, cfg, mode=mode, positions=positions,
             source=source, max_seq=max_seq, window_override=window_override,
-            live=live, pt=pt)
+            live=live, pt=pt, ctx=ctx)
 
         if self.repeats:
             def body(carry, xs):
@@ -201,6 +203,28 @@ class Model:
         if return_hidden:
             return logits, new_cache, x[:, 0]
         return logits, new_cache
+
+    def extend(self, params, cache, tokens, start, live, *, ctx: int,
+               pt=None):
+        """Append a block of tokens to the cache in one pass.
+
+        tokens (B, W); start (B,) the position of each row's first token;
+        live (B, W) the tokens to append: live token t of row b lands at
+        ``start[b]`` plus the number of live tokens before it, and the
+        others are skipped.  ``ctx`` (static, a multiple of the page size
+        on the paged layout) is an upper bound on every position the
+        block reaches.  Returns the new cache: no LM head, no reward head.
+        Defined for stacks of full and local attention layers.
+        """
+        cfg = self.cfg
+        n = live.astype(jnp.int32)
+        positions = start[:, None] + jnp.cumsum(n, axis=1) - n
+        x = embed_tokens(cfg, params["embed"], tokens)
+        _, new_cache, _ = self._run_stack(
+            params, x, mode="extend", positions=positions, cache=cache,
+            window_override=cfg.serve_window_override, live=live, pt=pt,
+            ctx=ctx)
+        return new_cache
 
     def reward_from_hidden(self, params, h):
         """PRM head on a hidden state (..., d) -> reward in [0,1]."""

@@ -159,6 +159,9 @@ class EngineStats:
     # submission if later, or its re-queue after a preemption)
     admissions: int = 0
     queue_wait_s: float = 0.0
+    # admitted and extended rows whose prompt prefill ran as one
+    # multi-token pass per model (the others ran the token-serial scan)
+    prefill_batched: int = 0
     # per-step trace arrays are bounded: at most ``trace_limit`` arrays are
     # retained per trace, while running moments keep exact aggregate
     # mean/variance for arbitrarily long serving runs (collect_stats=True
@@ -251,7 +254,7 @@ def merge_engine_stats(parts: Sequence[EngineStats]) -> EngineStats:
                 "prefill_tokens", "pages_evicted",
                 "decode_pages_published", "preemptions",
                 "resumes", "deadline_misses", "fallback_steps",
-                "admissions", "queue_wait_s")
+                "admissions", "queue_wait_s", "prefill_batched")
     for p in parts:
         with p._lock:
             for f in counters:
@@ -380,6 +383,19 @@ class GSIServingEngine:
         self.prefix_cache = bool(prefix_cache and paged
                                  and self._prefix_supported())
         self.decode_publish = bool(decode_publish and self.prefix_cache)
+        # prompt prefill runs as one multi-token pass per model
+        # (``Model.extend``) iff every layer of the three keeps its state
+        # in position-addressed K/V rows (recurrent state advances token
+        # by token), the pool stores those rows as computed (a quantized
+        # pool re-fits a page's scale on every token it takes, which only
+        # the token-serial scan reproduces), and no FFN routes to experts
+        # (a block's B*W tokens would share one capacity-bounded dispatch,
+        # where the scan's B tokens a step never overflow it)
+        self.batched_prefill = bool(
+            self._prefix_supported()
+            and not quant.is_quantized(kv_dtype)
+            and not any(m.cfg.num_experts
+                        for m in (self.draft, self.target, self.prm)))
         self.mesh = mesh
         self.device = None if mesh is not None else device
         if self.device is not None:
@@ -420,12 +436,11 @@ class GSIServingEngine:
             self.params = (params_s, params_b, params_p)
             # the shard_map'd jits need the state *structure* (dense vs
             # paged, batch size) — built lazily by fresh_state()
-            self._jit_step = self._jit_commit = None
+            self._jit_step = None
             self._jit_admit = self._jit_extend = None
             self._jit_draft_phase = self._jit_target_phase = None
         else:
             self._jit_step = self._bind(self._decode_core)
-            self._jit_commit = self._bind(self._commit)
             self._jit_admit = self._bind(self._admit)
             self._jit_extend = self._bind(self._extend)
             # standalone phase jits: not on the decode path (the fused
@@ -496,11 +511,7 @@ class GSIServingEngine:
                 return jitted(self.params, st, *extra)
             return call
 
-        def commit(params, st, tokens):
-            return self._commit(params, st, tokens)
-
         self._jit_step = wrap(self._decode_core, 2, (state_specs, R))
-        self._jit_commit = wrap(commit, 1, state_specs)
         self._jit_admit = wrap(self._admit, 4, state_specs)
         self._jit_extend = wrap(self._extend, 3, state_specs)
         self._jit_draft_phase = wrap(self._draft_phase, 1, R)
@@ -619,11 +630,9 @@ class GSIServingEngine:
         so they never decode or hold up ``run``'s all-done early exit.
         """
         B = prompts.shape[0]
-        prompts = np.asarray(prompts)
+        prompts = np.asarray(prompts, np.int32)
         state = self.fresh_state(B)
-        state["pending"] = jnp.asarray(prompts[:, 0], jnp.int32)
         done = (prompts == PAD).all(axis=1)
-        state["done"] = jnp.asarray(done)
         lengths = (prompts != PAD).sum(axis=1)
         self._known_done = done.copy()
         if self.paged:
@@ -633,10 +642,12 @@ class GSIServingEngine:
                         int(lengths[b]), self.gcfg.max_steps))
             state = self._assign_pages(state,
                                        np.maximum(lengths - 1, 0))
-        if prompts.shape[1] > 1:
-            state = self._with_gen(
-                self._jit_commit(state, jnp.asarray(prompts[:, 1:],
-                                                    jnp.int32)), state)
+        # the fresh rows are empty, so admitting every non-empty row is
+        # the whole prompt ingestion
+        state = self._with_gen(
+            self._jit_admit(state, jnp.asarray(~done), jnp.asarray(prompts),
+                            jnp.zeros((B,), jnp.int32),
+                            jnp.ones((B,), bool)), state)
         self._known_pos = np.maximum(lengths - 1, 0).astype(np.int64)
         return state
 
@@ -902,6 +913,48 @@ class GSIServingEngine:
             _, new["P"], _, _ = score_and_append(
                 self.prm, pp, caches["P"], state["pending"], state["pos"],
                 step_tokens, return_rewards=True, row_live=row_live, pt=pt)
+        return self._appended(state, new, pos, step_tokens, row_live)
+
+    def _prefill(self, params, state, step_tokens, row_live=None,
+                 reach=None):
+        """Append prompt tokens (B,L): ``_commit``'s result, computed as
+        one multi-token pass per model (``Model.extend``, no LM or reward
+        head) wherever ``batched_prefill`` allows, else by
+        ``_commit``'s token-serial scan.
+
+        The pass feeds what the scan feeds: ``pending`` then each
+        non-PAD token, the token fed at step t appended iff
+        ``step_tokens[:, t]`` is a live (non-PAD, ``row_live``) token, at
+        ``pos`` plus the appends before it.  ``reach`` (static) bounds the
+        positions any appended token can take; ``None`` is ``max_seq``."""
+        L = step_tokens.shape[1]
+        if not L or not self.batched_prefill:
+            return self._commit(params, state, step_tokens, row_live)
+        fed = jnp.concatenate([state["pending"][:, None], step_tokens],
+                              axis=1)
+        j = jnp.arange(L + 1)
+        last = jax.lax.cummax(jnp.where((fed != PAD) | (j == 0), j, 0),
+                              axis=1)
+        tokens = jnp.take_along_axis(fed, last, axis=1)[:, :L]
+        live = step_tokens != PAD
+        if row_live is not None:
+            live &= row_live[:, None]
+        ctx = min(self.nblk, -(-(reach or self.max_seq) // self.page_size))
+        new = {}
+        for key, name, model, p in (("S", "draft", self.draft, params[0]),
+                                    ("B", "target", self.target, params[1]),
+                                    ("P", "prm", self.prm, params[2])):
+            with jax.named_scope(name):
+                new[key] = model.extend(
+                    p, state["caches"][key], tokens, state["pos"], live,
+                    ctx=ctx * self.page_size, pt=state.get("pt"))
+        pos = state["pos"] + jnp.sum(live, axis=1).astype(state["pos"].dtype)
+        return self._appended(state, new, pos, step_tokens, row_live)
+
+    @staticmethod
+    def _appended(state, caches, pos, step_tokens, row_live):
+        """The state after ``step_tokens`` were appended: the new caches
+        and positions, the last appended token pending."""
         length = jnp.sum(step_tokens != PAD, axis=1)
         if row_live is not None:
             length = jnp.where(row_live, length, 0)
@@ -911,10 +964,10 @@ class GSIServingEngine:
                 step_tokens, jnp.maximum(length - 1, 0)[:, None],
                 axis=1)[:, 0],
             state["pending"])
-        out = {"caches": new, "pending": pending, "pos": pos,
+        out = {"caches": caches, "pending": pending, "pos": pos,
                "done": state["done"]}
-        if pt is not None:
-            out["pt"], out["scratch"] = pt, state["scratch"]
+        if "pt" in state:
+            out["pt"], out["scratch"] = state["pt"], state["scratch"]
             out["gen"] = state["gen"]
         return out
 
@@ -929,8 +982,11 @@ class GSIServingEngine:
         shared paged pools are never touched), bookkeeping is reset to the
         engine invariant (cache holds prompt[:-1], pending = prompt[-1],
         the matched prefix already living in spliced pages below
-        ``starts``), and the unmatched tail is teacher-forced through all
-        three models via the regular commit path with ``row_live`` masking.
+        ``starts``), and the unmatched tail is appended to all three
+        models by :meth:`_prefill` with ``row_live`` masking.  A tail
+        ends within the admission width (``starts + len(tail) <= Lt``),
+        so the pass attends over the first ``ceil(Lt / page_size)``
+        table blocks only.
 
         ``live`` (B,) marks which admitted rows hold their *whole* prompt:
         those come up decoding (done=False).  A chunked-prefill admission
@@ -951,16 +1007,17 @@ class GSIServingEngine:
                 new["pt"], new["scratch"] = state["pt"], state["scratch"]
                 new["gen"] = state["gen"]
             with jax.named_scope("gsi.commit"):
-                return self._commit(params, new, tails[:, 1:],
-                                    row_live=admit_mask)
+                return self._prefill(params, new, tails[:, 1:],
+                                     row_live=admit_mask,
+                                     reach=tails.shape[1])
 
     def _extend(self, params, state, mask, chunks, live):
         """Commit continuation prefill ``chunks`` (B,W; PAD-padded) into
         mid-prefill slots where ``mask`` is True (chunked prefill).
 
-        Each masked row's chunk is the next run of its prompt tokens: the
-        regular commit path teacher-forces ``pending`` + ``chunks[:, :-1]``
-        and leaves the chunk's last token pending — after the final chunk
+        Each masked row's chunk is the next run of its prompt tokens:
+        :meth:`_prefill` appends ``pending`` + ``chunks[:, :-1]`` and
+        leaves the chunk's last token pending — after the final chunk
         the row satisfies the same invariant a one-shot admit establishes
         (cache holds prompt[:-1], pending == prompt[-1], pos == len-1).
         ``live`` flips rows whose final chunk this is to done=False; rows
@@ -968,7 +1025,7 @@ class GSIServingEngine:
         """
         with jax.named_scope("gsi.extend"):
             with jax.named_scope("gsi.commit"):
-                new = self._commit(params, state, chunks, row_live=mask)
+                new = self._prefill(params, state, chunks, row_live=mask)
             new["done"] = jnp.where(mask, ~live, state["done"])
         return new
 

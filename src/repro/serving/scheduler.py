@@ -617,7 +617,8 @@ class GSIScheduler:
                     priority=req.priority, deadline_s=req.deadline_s)
             self._partial[slot] = resp
         self.stats.bump(admissions=len(batch), queue_wait_s=sum(
-            at - req.queued_at for req, _ in batch.values()))
+            at - req.queued_at for req, _ in batch.values()),
+            prefill_batched=len(batch) * self.engine.batched_prefill)
         self.state = self.engine.admit(self.state, mask, packed, starts,
                                        live=live)
         self.stats.prefill_commit_max = max(
@@ -671,6 +672,8 @@ class GSIScheduler:
         for slot, c in chunks.items():
             packed[slot, :c.size] = c
         self.state = self.engine.extend(self.state, mask, packed, live)
+        if self.engine.batched_prefill:
+            self.stats.bump(prefill_batched=len(chunks))
         self.stats.prefill_commit_max = max(
             self.stats.prefill_commit_max, total)
         for slot in np.nonzero(live)[0]:
