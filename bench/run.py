@@ -40,16 +40,15 @@ from typing import Optional  # noqa: E402
 
 import numpy as np  # noqa: E402
 
+from bench import arch  # noqa: E402
+from bench.errors import Refused  # noqa: E402
+
 ROOT = Path(__file__).resolve().parents[1]
 FILL_STEPS = 4          # engine steps between the clients' start and the window
 TRACE_SECONDS = 8.0     # the traced part of a --trace 1 window lasts at
 TRACE_STEPS = 3         # least this long and holds this many engine steps
 SAMPLE = 8              # requests compared with the reference per run
 TABLE_BLOCKS = 4        # table entries per slot that set-up warms
-
-
-class Refused(RuntimeError):
-    """The run cannot produce a result (no chip, a missing file)."""
 
 
 def log(msg: str) -> None:
@@ -119,17 +118,8 @@ def require_chips(count: int):
 # ---------------------------------------------------------------------------
 
 def model_config(role: str, m: dict, dtype: str):
-    from repro.config import ModelConfig
-    return ModelConfig(
-        name=f"{role}:{m['hf']}", family="dense",
-        num_layers=int(m["num_hidden_layers"]), d_model=int(m["hidden_size"]),
-        num_heads=int(m["num_attention_heads"]),
-        num_kv_heads=int(m["num_key_value_heads"]),
-        d_ff=int(m["intermediate_size"]), vocab_size=int(m["vocab_size"]),
-        head_dim=int(m["head_dim"]), rope_theta=float(m["rope_theta"]),
-        norm_eps=float(m["rms_norm_eps"]),
-        tie_embeddings=bool(m["tie_word_embeddings"]),
-        dtype=dtype, param_dtype=dtype, reward_head=bool(m.get("reward_head")))
+    """The program's configuration of one model, from its architecture."""
+    return arch.module(m).program(role, m, dtype)
 
 
 class CompileClock:

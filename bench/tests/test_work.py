@@ -5,9 +5,9 @@
 from bench import work
 
 # d 2, one layer, one head of size 2, one kv head, ff 3, vocabulary 5
-TOY = {"hidden_size": 2, "num_hidden_layers": 1, "num_attention_heads": 1,
-       "num_key_value_heads": 1, "head_dim": 2, "intermediate_size": 3,
-       "vocab_size": 5}
+TOY = {"arch": "dense", "hidden_size": 2, "num_hidden_layers": 1,
+       "num_attention_heads": 1, "num_key_value_heads": 1, "head_dim": 2,
+       "intermediate_size": 3, "vocab_size": 5}
 # per token: q, k, v, o projections 4 x 2 x 2 = 16, SwiGLU 3 x 2 x 3 = 18,
 # so 34 matmul parameters in the layer, 10 in the LM head; attention
 # costs 4 x 1 x 2 = 8 FLOPs per key; one key's K/V is 2 x 1 x 2 x 2 = 8

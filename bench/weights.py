@@ -1,20 +1,20 @@
 """Seeded random weights, made by the benchmark and handed to the program.
 
-A model is described by its configuration file's keys (``hidden_size``,
-``num_hidden_layers``, ...).  :func:`make_triple` builds the draft, target
+A model is described by its configuration file's keys; its architecture
+file (``bench/arch/<arch>.py``) turns them into the leaves, shapes and
+scales of :func:`shapes`.  :func:`make_triple` builds the draft, target
 and PRM trees for one device in ONE jitted call from the configuration's
-``weights_seed``, in the dtype they are served in.  The trees follow the
-layout the program's models take (``embed``, ``final_ln``, ``blocks.p0``
-with a leading layer axis, ``reward_head``); :func:`check_layout`
+``weights_seed``, in the dtype they are served in, each leaf from its own
+key: the index of its name among the model's sorted leaf names.  The
+trees follow the layout the program's models take; :func:`check_layout`
 compares them with the program's own parameter shapes at set-up, so a
 change of layout stops the run instead of feeding the wrong weights.
 
 Scales: the embedding, the untied LM head and the reward head N(0,
 0.02^2), the published ``initializer_range`` of the Qwen configurations;
 a projection inside a layer N(0, 1/fan_in) with the fan-in counted as
-the program's initializer counts it (``wo`` of attention over the head
-dimension); RMS-norm gains are stored as ``gamma`` in a ``(1 + gamma)``
-form and start at 0; the reward head's bias at 0.
+the program's initializer counts it; RMS-norm gains are stored as
+``gamma`` in a ``(1 + gamma)`` form and start at 0; biases at 0.
 """
 from __future__ import annotations
 
@@ -22,6 +22,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from bench import arch
 
 VOCAB_PAD = 512        # the program pads every vocabulary to this multiple
 INIT_RANGE = 0.02      # the published initializer_range
@@ -33,29 +35,7 @@ def padded_vocab(m: dict) -> int:
 
 def shapes(m: dict) -> dict:
     """Leaf name -> (shape, std) of one model; std 0 means zeros."""
-    d, L = int(m["hidden_size"]), int(m["num_hidden_layers"])
-    H, KV = int(m["num_attention_heads"]), int(m["num_key_value_heads"])
-    hd, ff, V = int(m["head_dim"]), int(m["intermediate_size"]), \
-        padded_vocab(m)
-    out = {
-        "embed/embedding": ((V, d), INIT_RANGE),
-        "final_ln": ((d,), 0.0),
-        "blocks/p0/ln1": ((L, d), 0.0),
-        "blocks/p0/ln2": ((L, d), 0.0),
-        "blocks/p0/attn/wq": ((L, d, H, hd), d ** -0.5),
-        "blocks/p0/attn/wk": ((L, d, KV, hd), d ** -0.5),
-        "blocks/p0/attn/wv": ((L, d, KV, hd), d ** -0.5),
-        "blocks/p0/attn/wo": ((L, H, hd, d), hd ** -0.5),
-        "blocks/p0/ffn/wi_gate": ((L, d, ff), d ** -0.5),
-        "blocks/p0/ffn/wi_up": ((L, d, ff), d ** -0.5),
-        "blocks/p0/ffn/wo": ((L, ff, d), ff ** -0.5),
-    }
-    if not m["tie_word_embeddings"]:
-        out["embed/unembed"] = ((d, V), INIT_RANGE)
-    if m.get("reward_head"):
-        out["reward_head/w"] = ((d, 1), INIT_RANGE)
-        out["reward_head/b"] = ((1,), 0.0)
-    return out
+    return arch.module(m).shapes(m)
 
 
 def _nest(flat: dict) -> dict:

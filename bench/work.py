@@ -18,12 +18,14 @@ tokens and ``n`` candidates:
 An admission feeds the prompt's unmatched tail, bar its last (pending)
 token, once through each model.
 
-FLOPs are 2 x the matmul parameters a token meets, plus attention at its
-real context: 4 x heads x head_dim x keys per layer.  The LM head counts
-only where logits are used: for draft and target tokens of a step, not
-for prefill and never for the PRM.  Attention bytes are the K/V a phase
-has to read once per layer: the request's shared context once for all
-its candidates, plus each candidate's own new keys.
+FLOPs are 2 x the matmul parameters a token meets (of an expert layer,
+the experts it is routed through), plus attention at its real context:
+4 x heads x head_dim x keys per layer, plus a recurrent layer's state
+update, whose state is read and written once per fed token.  The LM head
+counts only where logits are used: for draft and target tokens of a
+step, not for prefill and never for the PRM.  Attention bytes are the
+K/V a phase has to read once per layer: the request's shared context
+once for all its candidates, plus each candidate's own new keys.
 
 Not counted, being waste of an implementation: the commit's re-scoring
 of the chosen step, the fallback phase of requests that accepted, the
@@ -35,38 +37,43 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from bench import arch
+
 BF16 = 2
 
 
 @dataclass(frozen=True)
 class Cost:
-    """Per-token costs of one model, from its configuration keys."""
+    """Per-token costs of one model, from its configuration keys, counted
+    by its architecture file (``bench/arch/<arch>.py`` ``cost``)."""
     layers: int
-    body: int          # matmul parameters of the layers
+    body: int          # matmul parameters of the layers (experts: the
+                       # share a token is routed through)
     head: int          # LM head parameters (real vocabulary)
     attn: int          # 4 * heads * head_dim * layers: FLOPs per key
     kv: int            # K/V bytes per key, all layers
+    state_flops: int = 0   # a recurrent state's update, all layers
+    state_bytes: int = 0   # recurrent state read and written, all layers
 
     @classmethod
     def of(cls, m: dict) -> "Cost":
-        d, L = int(m["hidden_size"]), int(m["num_hidden_layers"])
-        H, KV = int(m["num_attention_heads"]), int(m["num_key_value_heads"])
-        hd, ff = int(m["head_dim"]), int(m["intermediate_size"])
-        body = L * (2 * d * H * hd + 2 * d * KV * hd + 3 * d * ff)
-        return cls(L, body, d * int(m["vocab_size"]), 4 * H * hd * L,
-                   2 * KV * hd * BF16 * L)
+        return arch.module(m).cost(m)
 
 
 @dataclass
 class Work:
-    flops: float = 0.0
+    flops: float = 0.0         # all of it, state updates included
     attn_flops: float = 0.0
     attn_bytes: float = 0.0
+    state_flops: float = 0.0
+    state_bytes: float = 0.0
 
     def add(self, other: "Work") -> None:
         self.flops += other.flops
         self.attn_flops += other.attn_flops
         self.attn_bytes += other.attn_bytes
+        self.state_flops += other.state_flops
+        self.state_bytes += other.state_bytes
 
 
 def feed(cost: Cost, positions, *, head: bool, shared_keys: int,
@@ -75,8 +82,10 @@ def feed(cost: Cost, positions, *, head: bool, shared_keys: int,
     reading ``shared_keys + own_keys`` keys of K/V once."""
     keys = sum(p + 1 for p in positions)
     mat = 2 * (cost.body + (cost.head if head else 0)) * len(positions)
-    return Work(mat + cost.attn * keys, cost.attn * keys,
-                cost.kv * (shared_keys + own_keys))
+    state = cost.state_flops * len(positions)
+    return Work(mat + cost.attn * keys + state, cost.attn * keys,
+                cost.kv * (shared_keys + own_keys), state,
+                cost.state_bytes * len(positions))
 
 
 def step(costs: dict, *, n: int, context: int, length: int,
